@@ -64,6 +64,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.engine import NetworkPlan, QuerySpec, SimEngine, get_policy
 from repro.p2psim import (SimParams, available_topologies,
                           barabasi_albert, build_topology,
@@ -550,6 +551,7 @@ def main() -> None:
                     help="CI smoke: smaller sweeps, fewer reps")
     ap.add_argument("--out", default="BENCH_multi_query.json")
     args = ap.parse_args()
+    enable_compile_cache()
     data = collect(fast=args.fast)
     with open(args.out, "w") as f:
         json.dump(data, f, indent=2)

@@ -34,6 +34,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.engine import (NetworkPlan, Overlay, QuerySpec, SimEngine,
                           get_policy)
 from repro.p2psim import SimParams, barabasi_albert, build_topology
@@ -240,6 +241,7 @@ def main() -> None:
                          "fast baseline)")
     ap.add_argument("--out", default="BENCH_overlay_dynamics.json")
     args = ap.parse_args()
+    enable_compile_cache()
     data = collect(fast=args.fast)
     with open(args.out, "w") as f:
         json.dump(data, f, indent=1)

@@ -12,6 +12,8 @@ import time
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks.multi_query import ALL as MULTI
     from benchmarks.paper_figures import ALL as FIGS
     from benchmarks.tpu_comm import ALL as COMM

@@ -35,13 +35,30 @@ from jax.sharding import PartitionSpec as P
 
 from repro import jaxcompat
 from repro.core import topology
-from repro.kernels.merge import merge_scorelists
 from repro.kernels.topk import local_topk
 
 
 # --------------------------------------------------------------------------
 # In-shard_map collective top-k
 # --------------------------------------------------------------------------
+
+def _merge_ranked(va, ia, vb, ib):
+    """Top-k of the union of two k-lists, ranked by (score descending,
+    global index ascending) — the order ``topk_ref`` gives the whole
+    vector.
+
+    Equal scores are common (f32 ``jax.random.normal`` repeats values in
+    its tail).  A merge that broke ties by list position would keep a
+    different owner under each schedule, and on each device of the
+    doubling and ring schedules; under a total order every schedule and
+    every device return exactly ``topk_ref``'s indices.
+    """
+    k = va.shape[-1]
+    neg, idx = jax.lax.sort((-jnp.concatenate([va, vb], axis=-1),
+                             jnp.concatenate([ia, ib], axis=-1)),
+                            num_keys=2)
+    return -neg[..., :k], idx[..., :k]
+
 
 def fd_topk_shard(local_scores: jax.Array, k: int, axis_name: str,
                   axis_size: int, *, schedule: str = "halving",
@@ -65,7 +82,7 @@ def fd_topk_shard(local_scores: jax.Array, k: int, axis_name: str,
         for perm in topology.doubling_rounds(axis_size):
             pv = jax.lax.ppermute(vals, axis_name, perm)
             pi = jax.lax.ppermute(idx, axis_name, perm)
-            vals, idx = merge_scorelists(vals, idx, pv, pi)
+            vals, idx = _merge_ranked(vals, idx, pv, pi)
         return vals, idx
 
     if schedule == "halving":
@@ -76,7 +93,7 @@ def fd_topk_shard(local_scores: jax.Array, k: int, axis_name: str,
             recv = jnp.isin(ax, jnp.asarray(sorted(receivers)))
             pv = jnp.where(recv, pv, -jnp.inf)
             pi = jnp.where(recv, pi, -1)
-            vals, idx = merge_scorelists(vals, idx, pv, pi)
+            vals, idx = _merge_ranked(vals, idx, pv, pi)
         # device 0 (query originator) now holds the final score-list;
         # broadcast it (the retrieval-phase "ask" fan-out).
         vals = jax.lax.psum(jnp.where(ax == 0, vals, 0.0), axis_name)
@@ -90,7 +107,7 @@ def fd_topk_shard(local_scores: jax.Array, k: int, axis_name: str,
         for perm in topology.ring_rounds(axis_size):
             relay_v = jax.lax.ppermute(relay_v, axis_name, perm)
             relay_i = jax.lax.ppermute(relay_i, axis_name, perm)
-            vals, idx = merge_scorelists(vals, idx, relay_v, relay_i)
+            vals, idx = _merge_ranked(vals, idx, relay_v, relay_i)
         return vals, idx
 
     raise ValueError(f"unknown schedule {schedule!r}")

@@ -114,10 +114,12 @@ def check_tolerance(precision: str, values_lo, owners_lo,
     v_lo, v_hi = v_lo.reshape(E, k), v_hi.reshape(E, k)
     o_lo, o_hi = o_lo.reshape(E, k), o_hi.reshape(E, k)
 
-    # owner-set recall per entry (empty slots excluded from the truth set)
+    # owner-set recall per entry (empty slots excluded from the truth
+    # set); owners are peer ids and repeat when a peer holds several of
+    # the top k, so recall is over the distinct owners
     recalls = np.ones(E)
     for e in range(E):
-        true = o_hi[e][o_hi[e] >= 0]
+        true = np.unique(o_hi[e][o_hi[e] >= 0])
         if true.size:
             got = o_lo[e][o_lo[e] >= 0]
             recalls[e] = np.intersect1d(true, got).size / true.size

@@ -66,7 +66,7 @@ class SimEngine(Engine):
       * ``"jax"`` — jitted XLA sweeps over the plan's depth-bucketed
         slices and static merge-fold schedule
         (``repro.engine.sim_jax``), routing the bottom-up k-list merge
-        through the Pallas bitonic kernel on TPU.
+        through the Pallas bitonic kernel on TPU (f32 / bf16).
         Bit-for-bit equal to the numpy backend in every RNG mode
         (the stochastic inputs are the same numpy draws), INCLUDING
         churn: finite ``lifetime_mean_s`` runs in the same jitted
@@ -76,9 +76,10 @@ class SimEngine(Engine):
         heuristic; that fallback is recorded on
         ``TopKResult.backend_used`` and warned about once per engine.
 
-    ``use_pallas`` (jax backend only): None = auto (Pallas on TPU, the
-    jnp merge oracle elsewhere); True forces the Pallas kernels, in
-    interpret mode off-TPU.
+    ``use_pallas`` (jax backend only): None = the fused jnp path on
+    every platform; True runs the Pallas kernels — compiled on TPU for
+    f32 / bf16, interpreted off-TPU, and refused for f64 on TPU (no f64
+    in Mosaic).
 
     ``precision`` (jax backend only): ``"f64"`` (default — the
     bit-exactness contract vs the scalar reference), ``"f32"`` or
@@ -341,11 +342,13 @@ class SimEngine(Engine):
         if prec != "f64" and self._validate_precision:
             # the tolerance contract: rerun the SAME entries in f64 and
             # measure recall / rtol of the reduced result against it
+            # (the f64 rerun takes the platform's f64 path: f64 never
+            # enters a compiled kernel, and both paths give the same bits)
             res64 = run_entries_jax(self.plan, sts, ent_st, ent_origin,
                                     ent_seeds, self.plan.top.n, p,
                                     pol.algorithm, pol.dynamic,
                                     pol.lifetime_mean_s, spec.independent,
-                                    use_pallas=self._use_pallas,
+                                    use_pallas=None,
                                     replicas=rep, precision="f64",
                                     shard=self._shard)
             report = check_tolerance(prec, vals, owns,

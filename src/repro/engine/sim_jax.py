@@ -4,18 +4,26 @@ The numpy engine's two hot phases are lowered to XLA:
 
   * the per-depth forward-phase sweep — query arrival times down the
     BFS tree plus the Strategy-1 "who-sent-first" edge reduction; the
-    per-level gather+add and the Appendix-A wait-propagation rule route
-    through ``repro.kernels.sweep`` (jnp oracles by default, Pallas
-    kernels with ``use_pallas=True`` — interpret mode on CPU, Mosaic
-    on TPU);
+    per-level gather+add is the fused jnp expression and the Appendix-A
+    wait-propagation rule routes through ``repro.kernels.sweep`` (jnp
+    oracle, or the Pallas kernel with ``use_pallas``);
   * the bottom-up k-list merge — the static fold schedule compiled into
     the plan's :class:`~repro.engine.plan.DepthSlices` executes only
     real pairwise merges (plus odd-slot carries), each one a fused
     bitonic merge network (max against the reversed partner, then
     log2(K) compare-exchange stages) — no ``top_k``, no sorts, no
-    scatters, which XLA:CPU punishes by orders of magnitude.  On TPU
-    (or with ``use_pallas=True``) the pairwise step routes through the
-    Pallas bitonic kernel in ``repro.kernels.merge`` instead.
+    scatters, which XLA:CPU punishes by orders of magnitude.  With
+    ``use_pallas`` the pairwise step routes through the Pallas bitonic
+    kernel in ``repro.kernels.merge`` instead (the same network).
+
+Kernel selection (``select_pallas``): ``use_pallas=None`` is the fused
+jnp path on every platform — no chip measurement has yet shown a
+kernel beating it.  ``use_pallas=True`` compiles the Pallas kernels on
+TPU for f32 / bf16 sweeps; float64 never enters a Mosaic kernel — the
+TPU has no native f64 — so ``use_pallas=True`` with f64 on TPU raises.
+Off-TPU, ``use_pallas=True`` runs the kernels in the Pallas interpreter
+(the CPU test path); nothing on the chip ever interprets
+(``repro.kernels.platform``).
 
 Everything stochastic is precomputed in numpy by the SHARED
 ``_precompute_draws`` (same RNG streams, same order as the scalar
@@ -24,8 +32,8 @@ code — so this backend is bit-for-bit equal to the numpy backend in
 every RNG mode, and therefore to ``run_query_reference`` wherever the
 numpy backend is (shared batch of one, independent streams).  With the
 default ``precision="f64"`` the sweeps trace and run inside
-``jaxcompat.enable_x64()``: float64 is what makes "same expression"
-mean "same bits".
+``jax.enable_x64()`` (scoped to the calling thread): float64 is what
+makes "same expression" mean "same bits".
 
 Reduced precision (``precision="f32"`` / ``"bf16"``) casts the shared
 numpy draws once on the host and runs the forward sweep and merge
@@ -47,12 +55,12 @@ so the serving layer can attribute latency honestly.  On accelerators
 the five per-entry draw buffers are donated to the sweep — the level
 arrays they produce replace them instead of doubling resident memory
 across depth levels (donation is a no-op on CPU and is disabled
-there).
+there; the choice is made at the first sweep call, not at import).
 
 ``shard=True`` runs the same sweep through ``shard_map`` over all
-local devices on the batch-entry axis (``jaxcompat`` mesh helpers, the
-same compat layer the multi-device :class:`~repro.engine.device`
-collectives are built on): entries are embarrassingly parallel, so
+local devices on the batch-entry axis (the ``jaxcompat`` mesh helpers
+the multi-device :class:`~repro.engine.device` collectives are built
+on): entries are embarrassingly parallel, so
 each device materializes only its slice of the (entries, n) working
 set — that is what lets a million-peer plan's sweep fit when a single
 host's slice would not.
@@ -92,7 +100,8 @@ from repro.engine.plan import DepthSlices, NetworkPlan
 from repro.engine.precision import np_dtype
 from repro.kernels.merge.merge import _next_pow2
 from repro.kernels.merge.ops import merge_scorelists
-from repro.kernels.sweep import level_arrivals, wait_propagate
+from repro.kernels.platform import on_tpu
+from repro.kernels.sweep import arrivals_ref, wait_propagate
 from repro.p2psim.metrics import ENTRY_BYTES_PAPER
 from repro.p2psim.simulate import (SimParams, _accept_urgent_origin,
                                    _cn_entries, _empty_out,
@@ -108,8 +117,11 @@ def _merge_desc(va, ia, vb, ib, valid_a=None, valid_b=None):
     ``max(a_i, reverse(b)_i)`` selects the top-K multiset of the union
     as a bitonic sequence; log2(K) half-cleaner stages re-sort it
     descending.  Pure elementwise min/max/select — XLA fuses the whole
-    network into one pass.  Exact for distinct values (and the -inf
-    padding only ever ties with itself beyond the real entries).
+    network into one pass.  Each stage is a true compare-exchange, so
+    the output holds each input entry at most once: on tied scores it
+    is still a top-k of the union with ``merge_ref``'s values, though
+    which tied entries survive the cut at k, and their order, may
+    differ from ``merge_ref``'s.
 
     ``valid_a`` / ``valid_b``: optional row masks — an invalid list
     (late child, churned-out peer, live-parent reroute slot) becomes
@@ -134,8 +146,11 @@ def _merge_desc(va, ia, vb, ib, valid_a=None, valid_b=None):
         shp = v.shape[:-1] + (K // (2 * s), 2, s)
         vp = jnp.flip(v.reshape(shp), axis=-2).reshape(v.shape)
         op = jnp.flip(o.reshape(shp), axis=-2).reshape(o.shape)
+        # a true compare-exchange: the low lane keeps its score unless
+        # the partner's is strictly larger and the high lane the mirror,
+        # so equal scores stay put and no owner is copied over another
         take_max = jnp.asarray(lane % (2 * s) < s)
-        keep = (v >= vp) == take_max
+        keep = jnp.where(take_max, v >= vp, v <= vp)
         v = jnp.where(keep, v, vp)
         o = jnp.where(keep, o, op)
         s //= 2
@@ -146,10 +161,8 @@ def _merge_lists(va, ia, vb, ib, use_pallas: bool,
                  valid_a=None, valid_b=None):
     """One pairwise descending k-list merge (top-k of the union)."""
     if use_pallas:
-        return merge_scorelists(
-            va, ia, vb, ib, use_pallas=True,
-            interpret=jax.default_backend() != "tpu",
-            valid_a=valid_a, valid_b=valid_b)
+        return merge_scorelists(va, ia, vb, ib, use_pallas=True,
+                                valid_a=valid_a, valid_b=valid_b)
     return _merge_desc(va, ia, vb, ib, valid_a, valid_b)
 
 
@@ -238,10 +251,9 @@ def _fd_sweep_impl(scores, t_exec, up_term, dn_term, death, wt, tqf, lam,
     the literal zero / -inf buffers below are created in the operand
     dtype precisely so no f32/bf16 value is ever silently upcast.
 
-    The per-level gather+add (forward flood) and the Appendix-A wait
-    rule dispatch through ``repro.kernels.sweep`` — jnp oracles or the
-    Pallas kernels depending on ``use_pallas`` (same bits either way
-    in f64).
+    The Appendix-A wait rule dispatches through ``repro.kernels.sweep``
+    — the jnp oracle or the Pallas kernel depending on ``use_pallas``
+    (same bits either way).
 
     Churn (``with_churn``): a peer dead at its would-be send time gets
     ``send = inf`` (its arrival can never release a waiting parent) and
@@ -252,11 +264,17 @@ def _fd_sweep_impl(scores, t_exec, up_term, dn_term, death, wt, tqf, lam,
     their lists to the grandparent".  All of it is masks over fixed
     shapes; the one scalar the masks hinge on — the peer's death time —
     comes from the shared numpy draws.
+
+    Each level's list-arrival times at the parent (``send + up_term``)
+    are returned with the send times, so the host's urgent-list pass
+    reads the very values the on-time mask compared rather than
+    re-adding them in its own arithmetic (which differs from an
+    emulated device float64 in the last bits, and would turn every
+    child that released its parent into a phantom late arrival).
     """
     E = t_exec.shape[0]
     K = _next_pow2(k)
     dmax = len(levels) - 1
-    interp = jax.default_backend() != "tpu"
 
     skip = None
     if with_st1:
@@ -268,11 +286,11 @@ def _fd_sweep_impl(scores, t_exec, up_term, dn_term, death, wt, tqf, lam,
     t_qs = [jnp.zeros((E, 1), t_exec.dtype)]
     for d in range(1, dmax + 1):
         lv = levels[d]
-        t_qs.append(level_arrivals(t_qs[d - 1], dn_term[:, lv["vv"]],
-                                   lv["par_pos"], use_pallas=use_pallas,
-                                   interpret=interp))
+        t_qs.append(arrivals_ref(t_qs[d - 1], dn_term[:, lv["vv"]],
+                                 lv["par_pos"]))
 
     send = [None] * (dmax + 1)
+    arr = [None] * (dmax + 1)
     m_v = [None] * (dmax + 1)
     m_o = [None] * (dmax + 1)
     alive = [None] * (dmax + 1)
@@ -294,7 +312,7 @@ def _fd_sweep_impl(scores, t_exec, up_term, dn_term, death, wt, tqf, lam,
         if "cnode" not in lv:                    # all leaves
             all_in = jnp.zeros((E, L), own_ready.dtype)
         else:
-            a0 = send[d + 1][:, lv["c_in_next"]] + up_term[:, lv["cnode"]]
+            a0 = arr[d + 1][:, lv["c_in_next"]]
             # the parent's send time (needed for the on-time mask)
             # depends on all_in, a pure max over ALL child arrivals
             # (dead children contribute inf) — mask-free, exactly as
@@ -307,11 +325,10 @@ def _fd_sweep_impl(scores, t_exec, up_term, dn_term, death, wt, tqf, lam,
         if with_churn:
             s, snd = wait_propagate(own_ready, all_in, deadline,
                                     death=death_lv,
-                                    use_pallas=use_pallas,
-                                    interpret=interp)
+                                    use_pallas=use_pallas)
         else:
             s = wait_propagate(own_ready, all_in, deadline,
-                               use_pallas=use_pallas, interpret=interp)
+                               use_pallas=use_pallas)
         if a0 is None:
             mv, mo = own_v, own_o
         else:
@@ -352,7 +369,9 @@ def _fd_sweep_impl(scores, t_exec, up_term, dn_term, death, wt, tqf, lam,
         else:
             send[d] = s
             m_v[d], m_o[d] = mv, mo
-    return (tuple(send), tuple(v[:, :, :k] for v in m_v),
+        if d:
+            arr[d] = send[d] + up_term[:, vv]
+    return (tuple(send), tuple(arr), tuple(v[:, :, :k] for v in m_v),
             tuple(o[:, :, :k] for o in m_o), skip,
             tuple(alive) if with_churn else None)
 
@@ -360,15 +379,21 @@ def _fd_sweep_impl(scores, t_exec, up_term, dn_term, death, wt, tqf, lam,
 _SWEEP_STATICS = ("k", "use_pallas", "with_st1", "with_churn",
                   "with_reroute")
 
-# buffer donation: each call converts fresh host draws to device
-# buffers; donating the five big per-entry operands lets XLA reuse
-# their memory for the level outputs instead of holding both live
-# across the whole depth loop.  CPU XLA does not implement donation
-# (it would only warn), so it is enabled on accelerators only.
-_fd_sweep = jax.jit(
-    _fd_sweep_impl, static_argnames=_SWEEP_STATICS,
-    donate_argnums=(() if jax.default_backend() == "cpu"
-                    else (0, 1, 2, 3, 4)))
+
+@functools.lru_cache(maxsize=None)
+def _fd_sweep():
+    """The jitted sweep, built at first use.
+
+    Buffer donation: each call converts fresh host draws to device
+    buffers; donating the five big per-entry operands lets XLA reuse
+    their memory for the level outputs instead of holding both live
+    across the whole depth loop.  CPU XLA does not implement donation
+    (it would only warn), so it is enabled on accelerators only — a
+    decision that needs the backend, hence not made at import.
+    """
+    donate = () if jax.default_backend() == "cpu" else (0, 1, 2, 3, 4)
+    return jax.jit(_fd_sweep_impl, static_argnames=_SWEEP_STATICS,
+                   donate_argnums=donate)
 
 
 @functools.lru_cache(maxsize=None)
@@ -383,8 +408,8 @@ def _sharded_fd_sweep(n_dev: int, k: int, use_pallas: bool,
     on its slice — no collectives needed, and no device ever
     materializes the full working set.  Static tables (wait budgets,
     level slices, fold schedules) are replicated; the per-entry draws
-    are split.  Built through the same ``jaxcompat`` mesh/shard_map
-    compat layer as the ``DeviceEngine`` collectives.
+    are split.  Built with the same ``jaxcompat`` mesh/shard_map
+    helpers as the ``DeviceEngine`` collectives.
     """
     P = jax.sharding.PartitionSpec
     mesh = jaxcompat.make_mesh((n_dev,), ("entries",))
@@ -480,6 +505,23 @@ def _pad_group(es: np.ndarray, E: int, n_dev: int):
     return np.concatenate([es, np.repeat(es[-1:], B - m)]), False
 
 
+def select_pallas(use_pallas: Optional[bool], precision: str) -> bool:
+    """Whether the sweep runs the Pallas kernels.
+
+    ``None`` (the default) is the fused jnp path on every platform: no
+    chip run has yet shown a kernel beating it (ROADMAP S5).  ``True``
+    compiles the kernels on TPU for f32 / bf16 and runs them in the
+    interpreter off-TPU; ``True`` on TPU with f64 raises — float64
+    never enters a Mosaic kernel.
+    """
+    if use_pallas and on_tpu() and precision == "f64":
+        raise ValueError(
+            "use_pallas=True with precision='f64' on TPU: float64 never "
+            "enters a Mosaic kernel; use use_pallas=None (the fused jnp "
+            "path) or precision='f32'/'bf16'")
+    return bool(use_pallas)
+
+
 def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
                     ent_origin: np.ndarray, seeds, n: int, p: SimParams,
                     algorithm: str, dynamic: bool, lifetime_mean_s: float,
@@ -515,9 +557,8 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
     out = _empty_out(E, k)
     out["jax_compile_s"] = 0.0
     out["jax_traces"] = 0
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
     fp64 = precision == "f64"
+    use_pallas = select_pallas(use_pallas, precision)
     if fp64:
         def cast(a):
             return a
@@ -529,7 +570,7 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
     # f64 needs the x64 flag for "same expression == same bits"; the
     # reduced modes must NOT enable it — the default f32 lattice is
     # exactly what keeps their int/float literals narrow
-    x64 = jaxcompat.enable_x64 if fp64 else contextlib.nullcontext
+    x64 = jax.enable_x64 if fp64 else contextlib.nullcontext
     n_dev = jax.local_device_count() if shard else 1
     if n_dev == 1:
         shard = False
@@ -573,6 +614,7 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
     # ---- FD: jitted forward + merge sweeps per origin -------------------
     with_reroute = churn and dynamic
     send_t = np.full((E, n), np.inf)
+    arr_t = np.full((E, n), np.inf)      # list arrival at the parent
     mvals = np.empty((E, n, k))
     mown = np.full((E, n, k), -1, np.int32)
     valid = np.zeros((E, n), bool) if churn else None
@@ -595,15 +637,15 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
             death = cast(_take(draws.death)) if churn else cast(
                 np.zeros(0))
             if shard:
-                fd = _sharded_fd_sweep(n_dev, k, bool(use_pallas),
+                fd = _sharded_fd_sweep(n_dev, k, use_pallas,
                                        with_st1, churn, with_reroute)
                 kw = {}
             else:
-                fd = _fd_sweep
-                kw = dict(k=k, use_pallas=bool(use_pallas),
+                fd = _fd_sweep()
+                kw = dict(k=k, use_pallas=use_pallas,
                           with_st1=with_st1, with_churn=churn,
                           with_reroute=with_reroute)
-            send_d, mv_d, mo_d, skip, alive_d = _timed(
+            send_d, arr_d, mv_d, mo_d, skip, alive_d = _timed(
                 fd, cast(_take(draws.scores)), cast(_take(draws.t_exec)),
                 cast(_take(draws.up_term)), cast(_take(draws.dn_term)),
                 death, cast(wait_time(st.ttl_rem, p)), tqf, lam,
@@ -611,6 +653,8 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
             for d, lv in enumerate(sl.levels):
                 rows = np.ix_(es, lv["vv"])
                 send_t[rows] = np.asarray(send_d[d])[:m]
+                if d:
+                    arr_t[rows] = np.asarray(arr_d[d])[:m]
                 mvals[rows] = np.asarray(mv_d[d])[:m]
                 mown[rows] = np.asarray(mo_d[d])[:m]
                 if churn:
@@ -642,7 +686,7 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
             if len(ch) == 0:
                 continue
             pr = st.parent[ch]
-            a = send_t[np.ix_(es, ch)] + draws.up_term[np.ix_(es, ch)]
+            a = arr_t[np.ix_(es, ch)]
             late = a > send_t[np.ix_(es, pr)]
             if churn:
                 # a dead child never went urgent; a dead parent's
@@ -674,6 +718,14 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
     truth_scores = (draws.scores if fp64
                     else cast(draws.scores).astype(np.float64))
     top_true_all = _true_topk_by_origin(truth_scores, sts, ent_of_st, k)
+    if fp64:
+        # a device that emulates float64 (TPU: about 49 significant
+        # bits) rounds the scores on upload, and the sweep returns them
+        # so rounded; round the truth the same way so the epilogue's
+        # exact value matching sees what the sweep saw (a no-op where
+        # float64 is native)
+        with x64():
+            top_true_all = np.asarray(jax.device_put(top_true_all))
     t_merge_done = send_t[np.arange(E), ent_origin] + p.merge_s
     _accept_urgent_origin(urgent, ent_origin, t_merge_done, mvals, mown,
                           valid, k)

@@ -1,94 +1,51 @@
-"""Version-compat shims over JAX API drift (0.4.x ↔ ≥0.6).
+"""Mesh and ``shard_map`` helpers shared by every multi-device call site.
 
-The repo is written against the modern surface — ``jax.shard_map``,
-``jax.sharding.AxisType`` / ``get_abstract_mesh`` / ``set_mesh`` — but
-must also run on 0.4.x jaxlibs where those names do not exist.  Every
-call site goes through this module instead of feature-testing inline.
+Written against the installed JAX line (0.9): one place fixes the
+defaults the repo wants everywhere —
 
-Mapping (new → old):
-  * ``jax.shard_map(..., axis_names=M, check_vma=False)``
-      → ``jax.experimental.shard_map.shard_map(..., check_rep=False,
-         auto=all_axes - M)``
-  * ``jax.make_mesh(..., axis_types=(Auto,)*r)``
-      → ``jax.make_mesh(...)`` (axis types predate 0.5; all axes are
-         implicitly auto)
-  * ``jax.sharding.set_mesh(mesh)`` → the mesh itself (old ``Mesh`` is
-      its own context manager and sets ``thread_resources``)
-  * ``jax.sharding.get_abstract_mesh()`` → the thread-resources
-      physical mesh; manual axes are detected via the bound axis env
-      (``axis_frame`` raises ``NameError`` outside shard_map).
+  * ``make_mesh`` builds meshes whose axes are all ``AxisType.Auto``;
+  * ``shard_map`` runs with replication checks off (``check_vma=False``)
+    and takes ``axis_names`` as the set of MANUAL axes;
+  * ``use_mesh`` / ``current_mesh`` / ``mesh_axis_names`` /
+    ``mesh_shape`` read and install the ambient mesh.
+
+float64 scoping needs no helper: ``jax.enable_x64()`` is a thread-local
+context manager.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-import jax.experimental  # noqa: F401  (feature-probed in enable_x64)
-
-_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-_HAS_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
-
-
-def axis_type_auto():
-    """``AxisType.Auto`` where it exists, else None (all axes are auto)."""
-    return jax.sharding.AxisType.Auto if _HAS_AXIS_TYPE else None
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with every axis auto, on old and new JAX."""
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
     kw = {} if devices is None else {"devices": devices}
-    if _HAS_AXIS_TYPE:
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kw)
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs, axis_names=None):
     """``jax.shard_map`` with replication checks off.
 
-    ``axis_names``: the MANUAL axes (None → all mesh axes manual), i.e.
-    the new-API meaning; mapped to old-API ``auto`` as the complement.
+    ``axis_names``: the MANUAL axes (None → all mesh axes manual).
     """
-    if _NEW_SHARD_MAP:
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = (frozenset() if axis_names is None
-            else frozenset(mesh.axis_names) - frozenset(axis_names))
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False, auto=auto)
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kw)
 
 
 def use_mesh(mesh):
     """Context manager installing ``mesh`` as the ambient mesh."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh                      # old JAX: Mesh is a context manager
+    return jax.sharding.set_mesh(mesh)
 
 
 def current_mesh():
     """The ambient (abstract) mesh, or None outside any mesh context."""
-    if _HAS_ABSTRACT_MESH:
-        m = jax.sharding.get_abstract_mesh()
-        if m is None or not m.axis_names:
-            return None
-        return m
-    from jax._src import mesh as mesh_lib
-    m = mesh_lib.thread_resources.env.physical_mesh
-    return None if m.empty else m
-
-
-def _axis_is_bound(name: str) -> bool:
-    """Old JAX: an axis bound in the axis env is manual (inside shard_map)."""
-    from jax._src import core as jcore
-    try:
-        jcore.axis_frame(name)
-        return True
-    except Exception:
-        return False
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or not m.axis_names:
+        return None
+    return m
 
 
 def mesh_axis_names(auto_only: bool = False) -> tuple:
@@ -99,41 +56,8 @@ def mesh_axis_names(auto_only: bool = False) -> tuple:
     names = tuple(m.axis_names)
     if not auto_only:
         return names
-    if _HAS_AXIS_TYPE and hasattr(m, "axis_types"):
-        auto = jax.sharding.AxisType.Auto
-        return tuple(n for n, t in zip(names, m.axis_types) if t == auto)
-    return tuple(n for n in names if not _axis_is_bound(n))
-
-
-def enable_x64():
-    """Context manager scoping float64 tracing to the enclosed block.
-
-    ``jax.experimental.enable_x64`` where it exists (the whole 0.4–0.7
-    line today), else a set/restore of the global flag.  The jitted
-    simulator sweeps (``repro.engine.sim_jax``) trace AND call inside
-    this context so their float64 parity contract never leaks the x64
-    default into the rest of the process (kernels, device tests and the
-    model stack all run the JAX-default float32).
-    """
-    if hasattr(jax.experimental, "enable_x64"):
-        return jax.experimental.enable_x64()
-
-    @contextlib.contextmanager
-    def _scoped():
-        old = bool(jax.config.jax_enable_x64)
-        jax.config.update("jax_enable_x64", True)
-        try:
-            yield
-        finally:
-            jax.config.update("jax_enable_x64", old)
-    return _scoped()
-
-
-def pallas_tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (≥0.7) / ``TPUCompilerParams`` (0.4–0.6)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    auto = jax.sharding.AxisType.Auto
+    return tuple(n for n, t in zip(names, m.axis_types) if t == auto)
 
 
 def mesh_shape() -> dict:

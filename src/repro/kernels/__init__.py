@@ -1,3 +1,3 @@
 from repro.kernels.merge import merge_pallas, merge_ref, merge_scorelists  # noqa: F401
-from repro.kernels.sweep import level_arrivals, wait_propagate  # noqa: F401
+from repro.kernels.sweep import wait_propagate  # noqa: F401
 from repro.kernels.topk import local_topk, topk_pallas, topk_ref  # noqa: F401

@@ -1,13 +1,20 @@
 """Score-list merge Pallas TPU kernel (Merge-and-Backward phase).
 
-Merges two descending k-lists into the top-k of their union using a
-bitonic merge network: since ``concat(a, reverse(b))`` is bitonic, the
-first k outputs of a bitonic sorting network of size 2k are obtained in
-log2(2k) compare-exchange stages — O(k log k) work, fully vectorized,
-no data-dependent control flow (MXU-free, pure VPU ops).
+Merges two descending k-lists into the top-k of their union with a
+bitonic merge network: with both lists padded to K = 2^ceil(log2 k),
+``max(a_j, b_{K-1-j})`` holds the top-K multiset of the union as a
+bitonic sequence, and log2(K) half-cleaner stages sort it descending —
+the same network, compare for compare, as the fused jnp merge
+``repro.engine.sim_jax._merge_desc``, so both paths give the same bits.
 
-Both lists live entirely in VMEM (k is tiny: 8..256); the batch dim is the
-grid.  Validated against ref.merge_ref in interpret mode.
+Layout: lists are stored position-major.  The wrapper turns the
+(batch, k) operands into (k, batch / 128, 128) arrays, so each list
+position is one (rows, 128) slab and every compare-exchange of the
+network is a full-tile elementwise min/max/select between two slabs —
+no lane shuffles, no gathers (Mosaic lowers neither a lane reverse nor
+a dynamic lane gather).  The grid walks the batch in blocks of
+``_ROWS`` x 128 lists.  Validated against ref.merge_ref in interpret
+mode.
 """
 from __future__ import annotations
 
@@ -16,10 +23,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro import jaxcompat
+from repro.kernels.platform import check_mosaic_dtype, resolve_interpret
 
 NEG_INF = float("-inf")
+_LANES = 128
+_ROWS = 32          # sublane rows per block: 32 x 128 lists per grid step
 
 
 def _next_pow2(x: int) -> int:
@@ -29,123 +39,118 @@ def _next_pow2(x: int) -> int:
     return p
 
 
-def _bitonic_descending(v, i):
-    """Full bitonic sort (descending) of (1, m) rows, m a power of two.
-
-    Implemented with static stage/substage loops (log^2 m compare-exchange
-    layers); each layer is a pair of where-selects over lane-shuffled copies
-    — Mosaic-friendly, no gathers.
-    """
-    m = v.shape[1]
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
-    size = 2
-    while size <= m:
-        stride = size // 2
-        while stride >= 1:
-            partner = lanes ^ stride
-            pv = _lane_swap(v, stride, m)
-            pi = _lane_swap(i, stride, m)
-            is_lo = (lanes & stride) == 0
-            # direction: descending when the size-block index is even
-            asc_block = (lanes & size) != 0
-            # keep max at lo for descending blocks, min at lo for ascending
-            take_max = jnp.logical_xor(is_lo, asc_block)
-            gt = v > pv
-            eq = v == pv
-            lower_idx = lanes < partner
-            # stable-ish tie-break: prefer element from lower lane
-            win = jnp.where(eq, lower_idx, gt)
-            keep = jnp.where(take_max, win, ~win)
-            v = jnp.where(keep, v, pv)
-            i = jnp.where(keep, i, pi)
-            stride //= 2
-        size *= 2
-    return v, i
-
-
-def _lane_swap(x, stride: int, m: int):
-    """x with lanes permuted by XOR(stride) — via reshape/flip, no gather."""
-    assert m % (2 * stride) == 0
-    y = x.reshape((-1, m // (2 * stride), 2, stride))
-    y = jnp.flip(y, axis=2)
-    return y.reshape(x.shape)
-
-
 def _merge_kernel(va_ref, ia_ref, vb_ref, ib_ref, *refs,
-                  k: int, m: int, dt, masked: bool):
+                  k: int, K: int, dt, masked: bool):
+    # the network only compares and selects, so bf16 lists can run it
+    # in f32 (exact both ways) — v5e's VPU has no bf16 compare
+    ct = jnp.float32 if dt == jnp.bfloat16 else dt
     if masked:
         ma_ref, mb_ref, vo_ref, io_ref = refs
     else:
         vo_ref, io_ref = refs
-    va = va_ref[...].astype(dt)
-    ia = ia_ref[...]
-    vb = vb_ref[...].astype(dt)
-    ib = ib_ref[...]
+    shape = va_ref.shape[1:]
+    pad_v = jnp.full(shape, NEG_INF, ct)
+    pad_i = jnp.full(shape, -1, jnp.int32)
+    a_v = [va_ref[j].astype(ct) for j in range(k)] + [pad_v] * (K - k)
+    a_i = [ia_ref[j] for j in range(k)] + [pad_i] * (K - k)
+    b_v = [vb_ref[j].astype(ct) for j in range(k)] + [pad_v] * (K - k)
+    b_i = [ib_ref[j] for j in range(k)] + [pad_i] * (K - k)
     if masked:
         # validity masking in VMEM: a dead peer's list becomes -inf rows
         # (it can never beat a live score) — pure select, no control flow
-        va = jnp.where(ma_ref[...] != 0, va, NEG_INF)
-        vb = jnp.where(mb_ref[...] != 0, vb, NEG_INF)
-    pad = m // 2 - k
-    if pad:
-        va = jnp.pad(va, ((0, 0), (0, pad)), constant_values=NEG_INF)
-        ia = jnp.pad(ia, ((0, 0), (0, pad)), constant_values=-1)
-        vb = jnp.pad(vb, ((0, 0), (0, pad)), constant_values=NEG_INF)
-        ib = jnp.pad(ib, ((0, 0), (0, pad)), constant_values=-1)
-    v = jnp.concatenate([va, vb], axis=1)
-    i = jnp.concatenate([ia, ib], axis=1)
-    v, i = _bitonic_descending(v, i)
-    vo_ref[...] = v[:, :k]
-    io_ref[...] = i[:, :k]
+        live_a = ma_ref[...] != 0
+        live_b = mb_ref[...] != 0
+        a_v = [jnp.where(live_a, x, NEG_INF) for x in a_v]
+        b_v = [jnp.where(live_b, x, NEG_INF) for x in b_v]
+    v, o = [], []
+    for j in range(K):
+        take = a_v[j] >= b_v[K - 1 - j]
+        v.append(jnp.where(take, a_v[j], b_v[K - 1 - j]))
+        o.append(jnp.where(take, a_i[j], b_i[K - 1 - j]))
+    s = K // 2
+    while s >= 1:
+        for lo in range(K):
+            if lo & s:
+                continue
+            hi = lo + s
+            # a true compare-exchange: equal scores stay put, so a tie
+            # never copies one owner over the other
+            swap = v[lo] < v[hi]
+            v[lo], v[hi] = (jnp.where(swap, v[hi], v[lo]),
+                            jnp.where(swap, v[lo], v[hi]))
+            o[lo], o[hi] = (jnp.where(swap, o[hi], o[lo]),
+                            jnp.where(swap, o[lo], o[hi]))
+        s //= 2
+    for j in range(k):
+        vo_ref[j] = v[j].astype(dt)
+        io_ref[j] = o[j]
+
+
+def _position_major(x, b: int, bp: int):
+    """(..., k) -> (k, bp / 128, 128): list positions lead, the batch
+    (zero-padded to ``bp``) fills the (sublane, lane) tile."""
+    k = x.shape[-1]
+    x = x.reshape((b, k)).T
+    if bp != b:
+        x = jnp.pad(x, ((0, 0), (0, bp - b)))
+    return x.reshape((k, bp // _LANES, _LANES))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def merge_pallas(vals_a, idx_a, vals_b, idx_b, *, interpret: bool = True,
+def merge_pallas(vals_a, idx_a, vals_b, idx_b, *, interpret=None,
                  valid_a=None, valid_b=None):
     """Merge two descending k-lists -> top-k of the union (descending).
 
-    float64 inputs (the x64 simulator sweep, interpret mode) merge in
-    float64; anything narrower keeps the float32 compute dtype.
+    f32 and bf16 lists merge in their own dtype; non-float / f16 inputs
+    keep the historical f32 compute dtype.  float64 lists run only in
+    interpret mode (the CPU path): a compiled kernel refuses them.
+    ``interpret=None`` interprets off-TPU and compiles on TPU.
 
     ``valid_a`` / ``valid_b``: optional boolean row masks over the
     leading axes (churned-out peers).  Masking happens inside the kernel
     on the VMEM-resident block — an invalid list's values become -inf
-    before the bitonic network runs, identical to pre-masking the HBM
-    input but without materializing a masked copy.
+    before the network runs, identical to pre-masking the HBM input but
+    without materializing a masked copy.
     """
+    interpret = resolve_interpret(interpret)
     lead = vals_a.shape[:-1]
     k = vals_a.shape[-1]
-    m = 2 * _next_pow2(k)
     dt = jnp.result_type(vals_a, vals_b)
     if not jnp.issubdtype(dt, jnp.floating) or dt == jnp.float16:
-        # non-float / f16 inputs keep the historical f32 compute dtype;
-        # f64, f32 and bf16 lists merge in their OWN dtype (the
-        # reduced-precision sweep must not silently upcast bf16)
         dt = jnp.promote_types(dt, jnp.float32)
-    va = vals_a.reshape((-1, k))
-    b = va.shape[0]
-    args = [va, idx_a.reshape((-1, k)), vals_b.reshape((-1, k)),
-            idx_b.reshape((-1, k))]
-    masked = valid_a is not None or valid_b is not None
-    spec = pl.BlockSpec((1, k), lambda i: (i, 0))
+    check_mosaic_dtype("merge_pallas", dt, interpret)
+    b = 1
+    for d in lead:
+        b *= d
+    bp = -(-b // _LANES) * _LANES
+    rows = bp // _LANES
+    tb = min(rows, _ROWS)
+    args = [_position_major(x, b, bp)
+            for x in (vals_a.astype(dt), idx_a.astype(jnp.int32),
+                      vals_b.astype(dt), idx_b.astype(jnp.int32))]
+    spec = pl.BlockSpec((k, tb, _LANES), lambda i: (0, i, 0))
     in_specs = [spec] * 4
+    masked = valid_a is not None or valid_b is not None
     if masked:
-        ones = jnp.ones(lead, jnp.int32)
-        args.append((ones if valid_a is None
-                     else valid_a.astype(jnp.int32)).reshape((-1, 1)))
-        args.append((ones if valid_b is None
-                     else valid_b.astype(jnp.int32)).reshape((-1, 1)))
-        in_specs = in_specs + [pl.BlockSpec((1, 1), lambda i: (i, 0))] * 2
-    kern = functools.partial(_merge_kernel, k=k, m=m, dt=dt, masked=masked)
+        for valid in (valid_a, valid_b):
+            m = (jnp.ones((b,), jnp.int32) if valid is None
+                 else valid.reshape((b,)).astype(jnp.int32))
+            args.append(jnp.pad(m, (0, bp - b)).reshape((rows, _LANES)))
+        in_specs += [pl.BlockSpec((tb, _LANES), lambda i: (i, 0))] * 2
+    kern = functools.partial(_merge_kernel, k=k, K=_next_pow2(k), dt=dt,
+                             masked=masked)
     vo, io = pl.pallas_call(
         kern,
-        grid=(b,),
+        grid=(pl.cdiv(rows, tb),),
         in_specs=in_specs,
         out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((b, k), dt),
-                   jax.ShapeDtypeStruct((b, k), jnp.int32)],
-        compiler_params=jaxcompat.pallas_tpu_compiler_params(
+        out_shape=[jax.ShapeDtypeStruct((k, rows, _LANES), dt),
+                   jax.ShapeDtypeStruct((k, rows, _LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)
-    return vo.reshape(lead + (k,)), io.reshape(lead + (k,))
+
+    def back(x):
+        return x.reshape((k, bp))[:, :b].T.reshape(lead + (k,))
+    return back(vo), back(io)

@@ -6,12 +6,13 @@ from repro.kernels.merge.ref import merge_ref
 
 
 def merge_scorelists(vals_a, idx_a, vals_b, idx_b, *, use_pallas: bool = False,
-                     interpret: bool = True, valid_a=None, valid_b=None):
+                     interpret=None, valid_a=None, valid_b=None):
     """Merge-and-Backward: top-k of the union of two descending k-lists.
 
     ``valid_a`` / ``valid_b``: optional boolean row masks over the leading
     axes — an invalid (churned-out) list contributes -inf values instead
     of branching; see the churn sweep in ``repro.engine.sim_jax``.
+    ``interpret=None`` interprets the kernel off-TPU only.
     """
     if use_pallas:
         return merge_pallas(vals_a, idx_a, vals_b, idx_b,

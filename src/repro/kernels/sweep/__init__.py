@@ -1,4 +1,3 @@
-from repro.kernels.sweep.ops import level_arrivals, wait_propagate  # noqa: F401
+from repro.kernels.sweep.ops import wait_propagate  # noqa: F401
 from repro.kernels.sweep.ref import arrivals_ref, wait_ref  # noqa: F401
-from repro.kernels.sweep.sweep import (arrivals_pallas,  # noqa: F401
-                                       wait_pallas)
+from repro.kernels.sweep.sweep import wait_pallas  # noqa: F401

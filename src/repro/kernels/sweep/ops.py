@@ -1,26 +1,20 @@
-"""Dispatch layer for the forward-sweep kernels (mirrors merge/ops.py).
+"""Dispatch layer for the wait-propagation kernel (mirrors merge/ops.py).
 
-``use_pallas=False`` routes to the jnp oracles (what XLA:CPU fuses
-best); ``use_pallas=True`` routes to the Pallas kernels —
-``interpret=True`` for the CPU CI path, compiled Mosaic on TPU.  Both
-paths produce the same bits in f64 and preserve f32 / bf16 dtypes.
+``use_pallas=False`` routes to the jnp oracle (what XLA fuses best);
+``use_pallas=True`` routes to the Pallas kernel — interpreted off-TPU,
+compiled Mosaic on TPU (``interpret=None``).  Both paths produce the
+same bits in f64 (interpret mode) and preserve f32 / bf16 dtypes.
 """
 from __future__ import annotations
 
-from repro.kernels.sweep.ref import arrivals_ref, wait_ref
-from repro.kernels.sweep.sweep import arrivals_pallas, wait_pallas
+import jax.numpy as jnp
 
-
-def level_arrivals(tq_prev, dn, par_pos, *, use_pallas: bool = False,
-                   interpret: bool = True):
-    """Level-d arrival times ``tq_prev[:, par_pos] + dn``."""
-    if use_pallas:
-        return arrivals_pallas(tq_prev, dn, par_pos, interpret=interpret)
-    return arrivals_ref(tq_prev, dn, par_pos)
+from repro.kernels.sweep.ref import wait_ref
+from repro.kernels.sweep.sweep import wait_pallas
 
 
 def wait_propagate(own_ready, all_in, deadline, *, death=None,
-                   use_pallas: bool = False, interpret: bool = True):
+                   use_pallas: bool = False, interpret=None):
     """Appendix-A send times; with ``death`` also the churn-masked send.
 
     Returns ``s`` (E, L), or ``(s, send)`` when ``death`` is given,
@@ -29,7 +23,6 @@ def wait_propagate(own_ready, all_in, deadline, *, death=None,
     if use_pallas:
         return wait_pallas(own_ready, all_in, deadline, death,
                            interpret=interpret)
-    import jax.numpy as jnp
     s = wait_ref(own_ready, all_in, deadline)
     if death is None:
         return s

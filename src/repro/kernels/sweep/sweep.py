@@ -1,24 +1,20 @@
-"""Pallas kernels for the per-depth gather / wait-propagation hot loop.
+"""Pallas kernel for the per-depth wait-propagation hot loop.
 
-Two kernels, both gridded over the entry axis (one program per query
-entry, embarrassingly parallel):
+``wait_pallas`` computes the Appendix-A send-time rule
+``min(max(own_ready, all_in), max(deadline, own_ready))`` in one
+elementwise pass over (entries, level width); the churn variant also
+emits the liveness-masked send time (``inf`` for a peer dead at its
+send time), so the mask costs no extra memory round trip.  The grid
+tiles the entry axis in blocks of up to ``_ROWS`` rows and the level
+axis in blocks of up to ``_COLS`` lanes (full extent when an axis is
+smaller, ``pl.cdiv`` otherwise).
 
-  * ``arrivals_pallas`` — the forward flood's fused gather+add: each
-    program gathers its entry's parent-level arrival row through the
-    static ``par_pos`` index vector and adds the level's downstream
-    link terms, producing the level's arrival row in one VMEM pass.
-  * ``wait_pallas`` — the Appendix-A send-time rule
-    ``min(max(own_ready, all_in), max(deadline, own_ready))`` fused
-    into one elementwise pass; the churn variant additionally emits the
-    liveness-masked send time (``inf`` for a peer dead at its send
-    time) so the mask costs no extra memory round trip.
-
-Both preserve the input dtype exactly (f64 / f32 / bf16 — the
-reduced-precision mode relies on no silent upcast) and group their
-float ops exactly as the jnp oracles in ``ref.py``, so the f64 path
-keeps the repo's bit-parity contract.  ``interpret=True`` runs the
-kernels through the Pallas interpreter — the CPU CI path; on TPU the
-same code compiles to Mosaic.
+The kernel preserves the input dtype (f32 / bf16, and f64 in interpret
+mode) and groups its float ops exactly as the jnp oracle in ``ref.py``,
+so the f64 CPU path keeps the repo's bit-parity contract.  The forward
+flood's gather+add (``ref.arrivals_ref``) has no kernel: it gathers
+along lanes with a data-dependent index, which Mosaic does not lower,
+and XLA fuses the jnp expression.
 """
 from __future__ import annotations
 
@@ -27,83 +23,68 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro import jaxcompat
+from repro.kernels.platform import check_mosaic_dtype, resolve_interpret
 
-
-def _arrivals_kernel(pp_ref, tq_ref, dn_ref, o_ref):
-    # one entry row: gather the parent level's arrivals through the
-    # static parent-position vector, add this level's link terms
-    o_ref[0, :] = (jnp.take(tq_ref[0, :], pp_ref[0, :], axis=0)
-                   + dn_ref[0, :])
+_ROWS = 64
+_COLS = 2048
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def arrivals_pallas(tq_prev, dn, par_pos, *, interpret: bool = True):
-    """Level arrivals ``tq_prev[:, par_pos] + dn`` as a Pallas kernel.
+def _load(ref):
+    # min / max / compare only select, so bf16 runs in f32 (exact both
+    # ways) — v5e's VPU has no bf16 compare
+    x = ref[...]
+    return x.astype(jnp.float32) if x.dtype == jnp.bfloat16 else x
 
-    ``tq_prev`` (E, L_prev), ``dn`` (E, L), ``par_pos`` (L,) int.
-    Returns (E, L) in ``result_type(tq_prev, dn)`` — same promotion as
-    the jnp expression, so f64 stays f64 and bf16 stays bf16.
-    """
-    E, Lp = tq_prev.shape
-    L = dn.shape[1]
-    dt = jnp.result_type(tq_prev, dn)
-    pp = jnp.asarray(par_pos, jnp.int32).reshape(1, L)
-    return pl.pallas_call(
-        _arrivals_kernel,
-        grid=(E,),
-        in_specs=[pl.BlockSpec((1, L), lambda i: (0, 0)),
-                  pl.BlockSpec((1, Lp), lambda i: (i, 0)),
-                  pl.BlockSpec((1, L), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, L), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((E, L), dt),
-        compiler_params=jaxcompat.pallas_tpu_compiler_params(
-            dimension_semantics=("parallel",)),
-        interpret=interpret)(pp, tq_prev.astype(dt), dn.astype(dt))
+
+def _send_time(r_ref, a_ref, d_ref):
+    own = _load(r_ref)
+    return jnp.minimum(jnp.maximum(own, _load(a_ref)),
+                       jnp.maximum(_load(d_ref), own))
 
 
 def _wait_kernel(r_ref, a_ref, d_ref, o_ref):
-    own = r_ref[0, :]
-    o_ref[0, :] = jnp.minimum(jnp.maximum(own, a_ref[0, :]),
-                              jnp.maximum(d_ref[0, :], own))
+    o_ref[...] = _send_time(r_ref, a_ref, d_ref).astype(o_ref.dtype)
 
 
 def _wait_churn_kernel(r_ref, a_ref, d_ref, death_ref, s_ref, snd_ref):
-    own = r_ref[0, :]
-    s = jnp.minimum(jnp.maximum(own, a_ref[0, :]),
-                    jnp.maximum(d_ref[0, :], own))
-    s_ref[0, :] = s
+    s = _send_time(r_ref, a_ref, d_ref)
+    s_ref[...] = s.astype(s_ref.dtype)
     # dead at send time -> an arrival that can never release a parent
-    snd_ref[0, :] = jnp.where(death_ref[0, :] >= s, s, jnp.inf)
+    snd_ref[...] = jnp.where(_load(death_ref) >= s, s,
+                             jnp.inf).astype(snd_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def wait_pallas(own_ready, all_in, deadline, death=None, *,
-                interpret: bool = True):
+                interpret=None):
     """Appendix-A send times as a Pallas kernel (optionally churned).
 
     All operands (E, L), dtype preserved.  Without ``death`` returns
     the raw send time ``s``; with ``death`` returns ``(s, send)`` where
     ``send`` is ``s`` masked to ``inf`` for peers dead at their send
-    time — the exact fill the churn sweep commits.
+    time — the exact fill the churn sweep commits.  ``interpret=None``
+    interprets off-TPU and compiles on TPU; a compiled kernel refuses
+    float64.
     """
+    interpret = resolve_interpret(interpret)
     E, L = own_ready.shape
     dt = jnp.result_type(own_ready, all_in, deadline)
-    spec = pl.BlockSpec((1, L), lambda i: (i, 0))
-    params = jaxcompat.pallas_tpu_compiler_params(
-        dimension_semantics=("parallel",))
+    check_mosaic_dtype("wait_pallas", dt, interpret)
+    te, tl = min(E, _ROWS), min(L, _COLS)
+    spec = pl.BlockSpec((te, tl), lambda i, j: (i, j))
+    call = functools.partial(
+        pl.pallas_call, grid=(pl.cdiv(E, te), pl.cdiv(L, tl)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret)
+    out = jax.ShapeDtypeStruct((E, L), dt)
     args = (own_ready.astype(dt), all_in.astype(dt), deadline.astype(dt))
     if death is None:
-        return pl.pallas_call(
-            _wait_kernel, grid=(E,), in_specs=[spec] * 3, out_specs=spec,
-            out_shape=jax.ShapeDtypeStruct((E, L), dt),
-            compiler_params=params, interpret=interpret)(*args)
-    out = pl.pallas_call(
-        _wait_churn_kernel, grid=(E,), in_specs=[spec] * 4,
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((E, L), dt),
-                   jax.ShapeDtypeStruct((E, L), dt)],
-        compiler_params=params,
-        interpret=interpret)(*args, death.astype(dt))
-    return tuple(out)
+        return call(_wait_kernel, in_specs=[spec] * 3, out_specs=spec,
+                    out_shape=out)(*args)
+    s, send = call(_wait_churn_kernel, in_specs=[spec] * 4,
+                   out_specs=[spec, spec],
+                   out_shape=[out, out])(*args, death.astype(dt))
+    return s, send

@@ -1,7 +1,7 @@
 """jit'd public wrapper for the local top-k kernel.
 
-``local_topk`` dispatches to the Pallas kernel (interpret mode on CPU,
-compiled on TPU) or the XLA reference, and always returns f32 values +
+``local_topk`` dispatches to the Pallas kernel (interpret mode off-TPU,
+compiled on TPU: ``interpret=None``) or the XLA reference, and always returns f32 values +
 int32 global indices in descending order.
 """
 from __future__ import annotations
@@ -14,7 +14,7 @@ from repro.kernels.topk.topk import topk_pallas
 
 def local_topk(scores: jax.Array, k: int, *, index_offset: int = 0,
                use_pallas: bool = False, tile_n: int = 1024,
-               interpret: bool = True):
+               interpret=None):
     """Top-k (vals, global idx) of ``scores`` along the last axis.
 
     The paper's Local Query Execution: score local items, keep the k best

@@ -9,9 +9,15 @@ local-query-execution phase with bounded memory:
         running_k = extract_top_k(cand)    # k iterations of max/argmax/mask
 
 Design notes (TPU mapping):
-  * tile_n is a multiple of 128 (lane dim) so loads are layout-friendly.
-  * extraction uses only max / argmax-free (iota==pos) select ops — no sort,
-    no gather — all Mosaic-lowerable vector primitives.
+  * blocks are (rows, tile_n): up to 8 score rows per block (the whole
+    batch when it is smaller) and tile_n a multiple of 128 lanes; the
+    grid is (cdiv(rows), cdiv(n, tile_n)) and the ragged last tile is
+    masked in-kernel, so the wrapper never pads the scores in HBM.
+  * extraction uses only max / min / select over the running list and
+    the tile — no sort, no gather, no lane concatenation — all
+    Mosaic-lowerable vector primitives.  The running list is searched
+    before the tile, which reproduces "first position of the max" over
+    ``concat(running_k, tile)``.
   * the running list lives in VMEM scratch and persists across the
     sequential grid dimension; output is written on the last tile.
   * numerically the kernel works in f32 regardless of input dtype (scores
@@ -29,39 +35,50 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import jaxcompat
+from repro.kernels.platform import check_mosaic_dtype, resolve_interpret
 
 NEG_INF = float("-inf")
+_LANES = 128
+_ROWS = 8           # score rows per block (the f32 sublane tile)
 
 
-def _extract_topk(cand_v, cand_i, k: int):
-    """k rounds of (max, first-argmax, mask) over the candidate row.
+def _extract_topk(run_v, run_i, x, base, k: int):
+    """k rounds of (max, first-argmax, mask) over ``run ++ x``.
 
-    cand_v: (1, m) f32, cand_v may contain -inf padding.
-    cand_i: (1, m) i32 global indices.
-    Returns (1, k) f32 values (descending) and (1, k) i32 indices.
+    run_v / run_i: (r, k) f32 / i32 running list (descending, may hold
+    -inf with index -1).  x: (r, t) f32 tile, -inf where masked; its
+    column c has global index ``base + c``.  Returns the (r, k) top-k of
+    the union, values descending, ties to the lower candidate position.
     """
-    m = cand_v.shape[1]
-    c_iota = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
-    k_iota = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    k_iota = jax.lax.broadcasted_iota(jnp.int32, run_v.shape, 1)
+    t_iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    big = jnp.int32(x.shape[1] + k)
 
     def body(j, carry):
-        cv, rv, ri = carry
-        mx = jnp.max(cv, axis=1, keepdims=True)                     # (1,1)
-        # first position attaining the max (tie-break: lowest index)
-        is_max = cv == mx
-        pos = jnp.min(jnp.where(is_max, c_iota, m), axis=1, keepdims=True)
-        sel = c_iota == pos
-        gi = jnp.sum(jnp.where(sel, cand_i, 0), axis=1, keepdims=True)
-        rv = jnp.where(k_iota == j, mx, rv)
-        ri = jnp.where(k_iota == j, gi, ri)
-        cv = jnp.where(sel, NEG_INF, cv)
-        return cv, rv, ri
+        rv, xv, ov, oi = carry
+        mx = jnp.maximum(jnp.max(rv, axis=1, keepdims=True),
+                         jnp.max(xv, axis=1, keepdims=True))      # (r,1)
+        pos_r = jnp.min(jnp.where(rv == mx, k_iota, big), axis=1,
+                        keepdims=True)
+        pos_t = jnp.min(jnp.where(xv == mx, t_iota, big), axis=1,
+                        keepdims=True)
+        in_run = pos_r < big
+        sel_r = (k_iota == pos_r) & in_run
+        sel_t = (t_iota == pos_t) & ~in_run
+        gi = jnp.where(in_run,
+                       jnp.sum(jnp.where(sel_r, run_i, 0), axis=1,
+                               keepdims=True),
+                       base + pos_t)
+        ov = jnp.where(k_iota == j, mx, ov)
+        oi = jnp.where(k_iota == j, gi, oi)
+        rv = jnp.where(sel_r, NEG_INF, rv)
+        xv = jnp.where(sel_t, NEG_INF, xv)
+        return rv, xv, ov, oi
 
-    rv0 = jnp.full((1, k), NEG_INF, jnp.float32)
-    ri0 = jnp.full((1, k), -1, jnp.int32)
-    _, rv, ri = jax.lax.fori_loop(0, k, body, (cand_v, rv0, ri0))
-    return rv, ri
+    ov0 = jnp.full(run_v.shape, NEG_INF, jnp.float32)
+    oi0 = jnp.full(run_v.shape, -1, jnp.int32)
+    _, _, ov, oi = jax.lax.fori_loop(0, k, body, (run_v, x, ov0, oi0))
+    return ov, oi
 
 
 def _topk_kernel(x_ref, vals_ref, idx_ref, run_v, run_i, *,
@@ -71,17 +88,14 @@ def _topk_kernel(x_ref, vals_ref, idx_ref, run_v, run_i, *,
 
     @pl.when(t == 0)
     def _init():
-        run_v[...] = jnp.full((1, k), NEG_INF, jnp.float32)
-        run_i[...] = jnp.full((1, k), -1, jnp.int32)
+        run_v[...] = jnp.full(run_v.shape, NEG_INF, jnp.float32)
+        run_i[...] = jnp.full(run_i.shape, -1, jnp.int32)
 
-    x = x_ref[...].astype(jnp.float32)                               # (1, tile_n)
+    x = x_ref[...].astype(jnp.float32)                     # (r, tile_n)
     local = t * tile_n + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    x = jnp.where(local < n_valid, x, NEG_INF)                       # mask pad
-    gidx = local + index_offset
-
-    cand_v = jnp.concatenate([run_v[...], x], axis=1)
-    cand_i = jnp.concatenate([run_i[...], gidx], axis=1)
-    rv, ri = _extract_topk(cand_v, cand_i, k)
+    x = jnp.where(local < n_valid, x, NEG_INF)             # ragged tail
+    rv, ri = _extract_topk(run_v[...], run_i[...], x,
+                           t * tile_n + index_offset, k)
     run_v[...] = rv
     run_i[...] = ri
 
@@ -94,40 +108,45 @@ def _topk_kernel(x_ref, vals_ref, idx_ref, run_v, run_i, *,
 @functools.partial(jax.jit, static_argnames=("k", "tile_n", "interpret",
                                              "index_offset"))
 def topk_pallas(scores: jax.Array, k: int, *, tile_n: int = 1024,
-                index_offset: int = 0, interpret: bool = True):
+                index_offset: int = 0, interpret=None):
     """Blocked top-k over the last axis of ``scores`` (any leading batch).
 
     Returns (vals f32 (..., k), idx i32 (..., k)) in descending value order.
+    ``tile_n`` must be a multiple of 128; ``interpret=None`` interprets
+    off-TPU and compiles on TPU.
     """
     if scores.ndim == 1:
         v, i = topk_pallas(scores[None], k, tile_n=tile_n,
                            index_offset=index_offset, interpret=interpret)
         return v[0], i[0]
+    interpret = resolve_interpret(interpret)
+    check_mosaic_dtype("topk_pallas", scores.dtype, interpret)
+    if tile_n % _LANES:
+        raise ValueError(f"tile_n={tile_n} is not a multiple of {_LANES}")
     lead = scores.shape[:-1]
     n = scores.shape[-1]
     if k > n:
         raise ValueError(f"k={k} > n={n}")
     x = scores.reshape((-1, n))
     b = x.shape[0]
-    n_tiles = max(1, -(-n // tile_n))
-    n_pad = n_tiles * tile_n
-    if n_pad != n:
-        x = jnp.pad(x, ((0, 0), (0, n_pad - n)), constant_values=NEG_INF)
+    tile_n = min(tile_n, -(-n // _LANES) * _LANES)
+    n_tiles = pl.cdiv(n, tile_n)
+    rows = min(b, _ROWS)
 
     kern = functools.partial(
         _topk_kernel, k=k, tile_n=tile_n, n_tiles=n_tiles, n_valid=n,
         index_offset=index_offset)
     vals, idx = pl.pallas_call(
         kern,
-        grid=(b, n_tiles),
-        in_specs=[pl.BlockSpec((1, tile_n), lambda i, t: (i, t))],
-        out_specs=[pl.BlockSpec((1, k), lambda i, t: (i, 0)),
-                   pl.BlockSpec((1, k), lambda i, t: (i, 0))],
+        grid=(pl.cdiv(b, rows), n_tiles),
+        in_specs=[pl.BlockSpec((rows, tile_n), lambda i, t: (i, t))],
+        out_specs=[pl.BlockSpec((rows, k), lambda i, t: (i, 0)),
+                   pl.BlockSpec((rows, k), lambda i, t: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((b, k), jnp.float32),
                    jax.ShapeDtypeStruct((b, k), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((1, k), jnp.float32),
-                        pltpu.VMEM((1, k), jnp.int32)],
-        compiler_params=jaxcompat.pallas_tpu_compiler_params(
+        scratch_shapes=[pltpu.VMEM((rows, k), jnp.float32),
+                        pltpu.VMEM((rows, k), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x)

@@ -9,8 +9,8 @@ Axis semantics (DESIGN.md §6):
 A FUNCTION, not a module constant — importing this module never touches
 jax device state (the dry-run must set XLA_FLAGS before first jax init).
 
-Mesh construction goes through ``repro.jaxcompat`` so the same code runs
-on 0.4.x jaxlibs (no ``axis_types``) and ≥0.6 (explicit auto axes).
+Mesh construction goes through ``repro.jaxcompat``, which makes every
+axis ``AxisType.Auto``.
 """
 from __future__ import annotations
 

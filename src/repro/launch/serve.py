@@ -311,6 +311,9 @@ def main_decode(argv=None):
 def main(argv=None):
     """Dispatch ``overlay`` / ``decode``; bare flags route to decode."""
     import sys
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     argv = list(sys.argv[1:]) if argv is None else list(argv)
     if argv and argv[0] == "overlay":
         return main_overlay(argv[1:])

@@ -151,6 +151,25 @@ def test_jax_backend_bit_exact_all_policies(name):
                           en.run(spec, name).metrics, name)
 
 
+@pytest.mark.parametrize("name", ["fd-dynamic", "fd-dynamic@600"])
+def test_jax_urgent_pass_reads_device_arrival_times(name):
+    """The urgent-list pass judges a child late by comparing its list's
+    arrival at the parent with the parent's send time, both as the sweep
+    computed them.  Re-adding the arrival on the host in float64 made
+    the child that released each waiting parent look late whenever the
+    device summed in lower precision (f32 here; TPU's emulated f64 too),
+    adding urgent messages the f64 run does not have."""
+    base, _, life = name.partition("@")
+    pol = get_policy(base)
+    if life:
+        pol = pol.variant(lifetime_mean_s=float(life))
+    ej = SimEngine(JTOP, PA, backend="jax", validate_precision=False)
+    spec = QuerySpec(origins=(0, 3), n_trials=4, rng="independent")
+    r64 = ej.run(spec, pol)
+    r32 = ej.run(dataclasses.replace(spec, precision="f32"), pol)
+    np.testing.assert_array_equal(r32.metrics.m_bw, r64.metrics.m_bw)
+
+
 def test_jax_backend_pallas_kernel_path():
     """use_pallas=True routes every pairwise merge through the Pallas
     bitonic kernel (interpret mode off-TPU) — same bits as the default

@@ -1,6 +1,8 @@
 """FD distributed top-k vs CN / CN* and the global oracle — on 8 fake
 devices in a subprocess (tests in-process must see 1 device)."""
 
+import pytest
+
 
 def test_fd_all_schedules_and_baselines(devices8):
     out = devices8("""
@@ -28,6 +30,33 @@ np.testing.assert_allclose(np.asarray(got), np.asarray(rows)[np.asarray(ref_i)],
 print("FD_OK")
 """)
     assert "FD_OK" in out
+
+
+@pytest.mark.parametrize("sched", ["halving", "doubling", "ring"])
+def test_fd_schedules_break_ties_like_topk_ref(devices8, sched):
+    """Tied scores: every schedule, on every device, returns lax.top_k's
+    indices (lowest index first among equal scores), and the gathered
+    rows are the rows of those indices."""
+    out = devices8(f"""
+import jax, jax.numpy as jnp, numpy as np
+from repro.core.fd import fd_topk, fd_topk_gather
+from repro.jaxcompat import make_mesh
+mesh = make_mesh((8,), ("model",))
+scores = jnp.round(jax.random.normal(jax.random.PRNGKey(3), (2, 4096)) * 2)
+rv, ri = jax.lax.top_k(scores, 20)
+assert len(np.unique(np.asarray(rv))) < 20          # ties inside the top k
+fv, fi = fd_topk(scores, 20, mesh, "model", schedule="{sched}")
+for shard in fi.addressable_shards:
+    np.testing.assert_array_equal(np.asarray(shard.data), np.asarray(ri))
+np.testing.assert_array_equal(np.asarray(fv), np.asarray(rv))
+rows = jax.random.normal(jax.random.PRNGKey(6), (4096, 16))
+_, gi, got = fd_topk_gather(scores[0], rows, 20, mesh, "model",
+                            schedule="{sched}")
+np.testing.assert_array_equal(np.asarray(gi), np.asarray(ri[0]))
+np.testing.assert_array_equal(np.asarray(got), np.asarray(rows)[np.asarray(ri[0])])
+print("TIES_OK")
+""")
+    assert "TIES_OK" in out
 
 
 def test_fd_with_batch_axes(devices8):

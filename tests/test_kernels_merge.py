@@ -17,15 +17,73 @@ def _mk_list(key, shape, k):
 @pytest.mark.parametrize("k", [1, 4, 7, 16, 20, 64])
 @pytest.mark.parametrize("lead", [(), (3,), (2, 5)])
 def test_merge_matches_ref(k, lead):
+    _check_merge_matches_ref(k, lead)
+
+
+@pytest.mark.parametrize("k", [8, 20])
+def test_merge_matches_ref_multi_block(k):
+    """A batch of 4100 lists spans two (32 x 128)-list blocks, the second
+    one ragged: the position-major tiling must not mix lists."""
+    _check_merge_matches_ref(k, (4100,))
+
+
+def _check_merge_matches_ref(k, lead):
     ka, kb = jax.random.split(jax.random.PRNGKey(0))
     va, ia = _mk_list(ka, lead, k)
     vb, ib = _mk_list(kb, lead, k)
     v1, i1 = merge_pallas(va, ia, vb, ib)
     v2, i2 = merge_ref(va, ia, vb, ib)
-    np.testing.assert_allclose(np.asarray(v1), np.asarray(v2))
-    # indices may differ only on tied values
-    same = np.asarray(v1) == np.asarray(v2)
-    assert same.all()
+    np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
+    # distinct random scores: no ties, so the owners agree too
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+
+
+def _tied_lists(k, rows=64, seed=3):
+    """Descending lists drawn from a few score levels, so ties within
+    and across the two lists are everywhere; every entry has its own
+    owner (a: 0..k-1, b: 1000..1000+k-1)."""
+    rng = np.random.default_rng(seed)
+    levels = np.array([1.0, 0.5, 0.25, -np.inf], np.float32)
+    va = -np.sort(-rng.choice(levels[:3], (rows, k)), axis=1)
+    vb = -np.sort(-rng.choice(levels, (rows, k)), axis=1)
+    ia = np.broadcast_to(np.arange(k, dtype=np.int32), (rows, k))
+    return va, ia, vb, ia + 1000
+
+
+@pytest.mark.parametrize("k", [2, 5, 16, 20])
+def test_merge_ties_lose_no_owner(k):
+    """On tied scores both merge networks (the Pallas kernel and the
+    fused jnp ``_merge_desc``) give merge_ref's values, and each output
+    is an input entry used once — a tie never copies one owner over
+    another.  Above the score at the cut-off the owner set is
+    merge_ref's; at the cut-off any of the tied entries may survive.
+    The two networks agree bit for bit."""
+    from repro.engine.sim_jax import _merge_desc
+    from repro.kernels.merge.merge import _next_pow2
+    va, ia, vb, ib = _tied_lists(k)
+    if k == 2:                       # the smallest case, written out
+        va[0], vb[0] = [1.0, 0.5], [1.0, 0.25]
+    rv, ri = (np.asarray(x) for x in merge_ref(va, ia, vb, ib))
+    kv, ki = (np.asarray(x) for x in merge_pallas(va, ia, vb, ib))
+    pad = _next_pow2(k) - k
+
+    def padded(x, fill):
+        return np.pad(x, ((0, 0), (0, pad)), constant_values=fill)
+    dv, di = _merge_desc(padded(va, -np.inf), padded(ia, -1),
+                         padded(vb, -np.inf), padded(ib, -1))
+    dv, di = np.asarray(dv)[:, :k], np.asarray(di)[:, :k]
+    for v, i in ((kv, ki), (dv, di)):
+        np.testing.assert_array_equal(v, rv)
+        for r in range(len(v)):
+            score = dict(zip(ia[r], va[r])) | dict(zip(ib[r], vb[r]))
+            assert len(set(i[r])) == k                  # no owner twice
+            assert [score[o] for o in i[r]] == list(v[r])
+            above = v[r] > v[r, -1]
+            assert set(i[r][above]) == set(ri[r][above])
+    np.testing.assert_array_equal(kv, dv)
+    np.testing.assert_array_equal(ki, di)
+    if k == 2:
+        assert sorted(ki[0]) == [0, 1000]
 
 
 def test_merge_identity():
@@ -95,8 +153,7 @@ def test_merge_valid_masks_match_premasked(k):
 def test_merge_float64_passthrough():
     """float64 lists (the x64 simulator sweep) merge in float64 on both
     the Pallas kernel and the jnp oracle — no silent f32 downcast."""
-    from repro import jaxcompat
-    with jaxcompat.enable_x64():
+    with jax.enable_x64():
         rng = np.random.default_rng(0)
         va = np.sort(rng.random((4, 8)))[:, ::-1].copy()
         vb = np.sort(rng.random((4, 8)))[:, ::-1].copy()

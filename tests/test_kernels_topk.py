@@ -9,7 +9,9 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels.topk import local_topk, topk_pallas, topk_ref
 
 
-@pytest.mark.parametrize("shape", [(128,), (1, 1000), (3, 777), (2, 4, 4096)])
+# (10, 300) spans two 8-row blocks; (2, 4, 4096) flattens to one full one
+@pytest.mark.parametrize("shape", [(128,), (1, 1000), (3, 777), (2, 4, 4096),
+                                   (10, 300)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float16])
 @pytest.mark.parametrize("k", [1, 8, 20])
 def test_topk_matches_ref(shape, dtype, k):

@@ -95,3 +95,17 @@ def test_fd_stats_rejects_reduced_precision():
     _, eng = _engines(16, 2, 0, precision="f32")
     with pytest.raises(ValueError, match="fd-stats"):
         eng.run(QuerySpec(origins=(0,)), "fd-stats")
+
+
+def test_tolerance_recall_counts_distinct_owners():
+    """Owners are peer ids and repeat when one peer holds several of
+    the top k: identical answers have recall 1.0, and recall measures
+    the distinct owners recovered."""
+    from repro.engine.precision import check_tolerance
+    vals = np.array([[0.9, 0.8, 0.7, 0.6]])
+    owners = np.array([[3, 3, 5, -1]])
+    rep = check_tolerance("f32", vals, owners, vals, owners)
+    assert rep.recall == 1.0 and rep.ok
+    rep = check_tolerance("f32", vals, np.array([[3, 3, 4, -1]]),
+                          vals, owners)
+    assert rep.recall == 0.5
