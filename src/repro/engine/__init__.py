@@ -14,7 +14,8 @@ NetworkPlan, across the sim and device backends.
 
 ``SimEngine(backend="jax")`` lowers the forward and merge sweeps to
 jitted JAX over the plan's cached ``DepthSlices`` (``sim_jax`` is
-imported lazily, so the default numpy path stays JAX-free);
+imported lazily, so the default numpy path traces and compiles
+nothing; the package imports JAX only for the profiler's trace spans);
 ``DeviceEngine`` exposes the same surface over the JAX shard_map
 collectives (also imported lazily).
 

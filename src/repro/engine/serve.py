@@ -37,7 +37,7 @@ asserted by tests/test_serving.py and the ``serving`` benchmark suite).
         handles = [server.submit(QuerySpec(origins=(o,), seed=s), "cn")
                    for s, o in enumerate(origins)]
         results = [h.result(timeout=5) for h in handles]
-    server.metrics().batch_hist                # {sweep size: count}
+    server.metrics().batch_hist                # {group size: count}
 
 ``benchmarks/loadgen.py`` drives this layer at ramping concurrency and
 emits the ``BENCH_serving.json`` suite; ``python -m repro.launch.serve
@@ -52,6 +52,7 @@ import time
 from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.engine.api import Engine, Policy, QuerySpec, TopKResult
 
@@ -97,7 +98,8 @@ class ServerMetrics:
     Counters count REQUESTS (``submitted`` includes everything accepted
     into the queue; ``shed`` requests were never queued).  ``batch_hist``
     histograms ``TopKResult.batch_size`` over served requests — how many
-    requests shared each executed sweep; ``dispatch_hist`` histograms
+    requests shared each coalesced ``run_many`` group (the jax backend
+    runs a group as one sweep per origin); ``dispatch_hist`` histograms
     how many requests each dispatcher cycle pulled.  ``latency`` /
     ``queue_s`` / ``run_s`` are ``None`` until a request completes.
 
@@ -353,9 +355,11 @@ class QueryServer:
         shapes: for each ``b`` the spec is replicated ``b`` times
         through ``run_many``, exactly the call the dispatcher makes for
         a coalesced batch of ``b`` identical requests.  The jax backend
-        pads entry batches to power-of-two buckets, so warming
-        ``(1, max_batch)`` covers every batch size in between — live
-        dispatches then report ``compile_s == 0``."""
+        pads each origin's entries to a power-of-two bucket and compiles
+        one program per bucket, so warm every power of two up to
+        ``max_batch`` (``(1, 2, 4, 8)`` for 8): ``(1, max_batch)``
+        leaves the buckets between cold.  Live dispatches whose buckets
+        were warmed report ``compile_s == 0``."""
         name = self._resolve_engine(engine)
         eng = self.engines[name]
         if batch_sizes:
@@ -426,20 +430,22 @@ class QueryServer:
             except queue.Empty:
                 continue
             batch = [first]
-            window_end = time.perf_counter() + cfg.batch_window_s
-            while len(batch) < cfg.max_batch:
-                try:                       # drain what's already there
-                    batch.append(self._queue.get_nowait())
-                    continue
-                except queue.Empty:
-                    pass
-                rem = window_end - time.perf_counter()
-                if rem <= 0:
-                    break
-                try:                       # linger for stragglers
-                    batch.append(self._queue.get(timeout=rem))
-                except queue.Empty:
-                    break
+            with TraceAnnotation("fd.server.linger") as span:
+                window_end = time.perf_counter() + cfg.batch_window_s
+                while len(batch) < cfg.max_batch:
+                    try:                   # drain what's already there
+                        batch.append(self._queue.get_nowait())
+                        continue
+                    except queue.Empty:
+                        pass
+                    rem = window_end - time.perf_counter()
+                    if rem <= 0:
+                        break
+                    try:                   # linger for stragglers
+                        batch.append(self._queue.get(timeout=rem))
+                    except queue.Empty:
+                        break
+                span.set_metadata(requests=len(batch))
             try:
                 self._dispatch(batch)
             finally:
@@ -447,46 +453,54 @@ class QueryServer:
                     self._queue.task_done()
 
     def _dispatch(self, batch: List[QueryHandle]) -> None:
-        """Execute one dequeued batch: timeouts, per-engine run_many."""
+        """Execute one dequeued batch: timeouts, per-engine run_many.
+
+        Dispatches are numbered from 1 in the order they run; each
+        result carries its dispatch's number in ``extras["dispatch"]``,
+        the ``dispatch`` stat of the ``fd.server.dispatch`` span."""
         now = time.perf_counter()
         with self._lock:
             self._dispatch_sizes[len(batch)] = \
                 self._dispatch_sizes.get(len(batch), 0) + 1
-        by_engine: Dict[str, List[QueryHandle]] = {}
-        for h in batch:
-            if h.deadline is not None and now >= h.deadline:
+            number = sum(self._dispatch_sizes.values())
+        with TraceAnnotation("fd.server.dispatch", dispatch=number,
+                             requests=len(batch)):
+            by_engine: Dict[str, List[QueryHandle]] = {}
+            for h in batch:
+                if h.deadline is not None and now >= h.deadline:
+                    with self._lock:
+                        self._counters["timed_out"] += 1
+                    h._complete(None, RequestTimeout(
+                        "request waited "
+                        f"{now - h.t_submit:.3f} s in queue, past its "
+                        "deadline; dropped before execution"))
+                    continue
+                by_engine.setdefault(h.engine_name, []).append(h)
+            for name, handles in by_engine.items():
+                try:
+                    results = self.engines[name].run_many(
+                        [h.spec for h in handles],
+                        [h.policy for h in handles])
+                except Exception as e:         # noqa: BLE001 — the whole
+                    with self._lock:           # group shares the failure
+                        self._counters["failed"] += len(handles)
+                    for h in handles:
+                        h._complete(None, e)
+                    continue
+                done = time.perf_counter()
                 with self._lock:
-                    self._counters["timed_out"] += 1
-                h._complete(None, RequestTimeout(
-                    "request waited "
-                    f"{now - h.t_submit:.3f} s in queue, past its "
-                    "deadline; dropped before execution"))
-                continue
-            by_engine.setdefault(h.engine_name, []).append(h)
-        for name, handles in by_engine.items():
-            try:
-                results = self.engines[name].run_many(
-                    [h.spec for h in handles],
-                    [h.policy for h in handles])
-            except Exception as e:             # noqa: BLE001 — the whole
-                with self._lock:               # group shares the failure
-                    self._counters["failed"] += len(handles)
-                for h in handles:
-                    h._complete(None, e)
-                continue
-            done = time.perf_counter()
-            with self._lock:
+                    for h, res in zip(handles, results):
+                        res.queue_s = now - h.t_submit
+                        res.extras["dispatch"] = number
+                        self._counters["served"] += 1
+                        self._batch_hist[res.batch_size] = \
+                            self._batch_hist.get(res.batch_size, 0) + 1
+                        self._records.append(
+                            (done - h.t_submit, res.queue_s, res.run_s))
+                    if len(self._records) > 200_000:   # bound the buffer
+                        del self._records[:100_000]
                 for h, res in zip(handles, results):
-                    res.queue_s = now - h.t_submit
-                    self._counters["served"] += 1
-                    self._batch_hist[res.batch_size] = \
-                        self._batch_hist.get(res.batch_size, 0) + 1
-                    self._records.append(
-                        (done - h.t_submit, res.queue_s, res.run_s))
-                if len(self._records) > 200_000:   # bound the buffer
-                    del self._records[:100_000]
-            for h, res in zip(handles, results):
-                h._complete(res, None)
+                    h._complete(res, None)
 
     def _fail_pending(self, err: ServerError) -> None:
         """Complete everything still queued with ``err``."""

@@ -22,6 +22,7 @@ import warnings
 from typing import List, Optional, Sequence, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.engine.api import (PRECISIONS, Engine, Policy, QuerySpec,
                               TopKResult, get_policy)
@@ -227,93 +228,99 @@ class SimEngine(Engine):
         spec; ``TopKResult.batch_size`` records how many requests shared
         the executed sweep.
         """
-        pols = self._zip_policies(specs, policies)
-        results: List[Optional[TopKResult]] = [None] * len(specs)
-        groups: dict = {}               # signature -> [request index]
-        for i, (spec, pol) in enumerate(zip(specs, pols)):
-            p = self._effective(spec, params)
-            if not self._coalescable(spec, pol):
-                results[i] = self._execute(spec, pol, p)
-                continue
-            prec = spec.precision or self._precision
-            groups.setdefault((pol, p.k, p.latency_model, prec),
-                              []).append(i)
-        for (pol, k, lm, prec), idxs in groups.items():
-            if len(idxs) == 1:          # nothing to fuse: direct path
-                i = idxs[0]
-                results[i] = self._execute(
-                    specs[i], pol, self._effective(specs[i], params))
-                continue
-            origins, seeds, shapes = [], [], []
-            for i in idxs:
-                spec = specs[i]
+        with TraceAnnotation("fd.engine.run_many",
+                             requests=len(specs)) as span:
+            pols = self._zip_policies(specs, policies)
+            results: List[Optional[TopKResult]] = [None] * len(specs)
+            groups: dict = {}               # signature -> [request index]
+            for i, (spec, pol) in enumerate(zip(specs, pols)):
                 p = self._effective(spec, params)
-                origins.append(np.repeat(
-                    np.asarray(spec.origins, np.int64), spec.n_trials))
-                seeds.append(self._entry_seeds(spec, p))
-                shapes.append((len(spec.origins), spec.n_trials))
-            fused = QuerySpec(
-                origins=tuple(int(o) for o in np.concatenate(origins)),
-                n_trials=1, k=k, latency_model=lm, precision=prec,
-                seeds=np.concatenate(seeds)[:, None])
-            res = self._execute(fused, pol,
-                                self._effective(fused, params))
-            lo = 0
-            for i, (Q, T) in zip(idxs, shapes):
-                hi = lo + Q * T
-                results[i] = dataclasses.replace(
-                    res, metrics=_slice_rows(res.metrics, lo, Q, T),
-                    values=(None if res.values is None else
-                            res.values.reshape(-1, k)[lo:hi]
-                            .reshape(Q, T, k)),
-                    indices=(None if res.indices is None else
-                             res.indices.reshape(-1, k)[lo:hi]
-                             .reshape(Q, T, k)),
-                    batch_size=len(idxs), extras=dict(res.extras))
-                lo += Q * T
-        return results
+                if not self._coalescable(spec, pol):
+                    results[i] = self._execute(spec, pol, p)
+                    continue
+                prec = spec.precision or self._precision
+                groups.setdefault((pol, p.k, p.latency_model, prec),
+                                  []).append(i)
+            span.set_metadata(groups=len(groups) + sum(
+                r is not None for r in results))
+            for (pol, k, lm, prec), idxs in groups.items():
+                if len(idxs) == 1:          # nothing to fuse: direct path
+                    i = idxs[0]
+                    results[i] = self._execute(
+                        specs[i], pol, self._effective(specs[i], params))
+                    continue
+                origins, seeds, shapes = [], [], []
+                for i in idxs:
+                    spec = specs[i]
+                    p = self._effective(spec, params)
+                    origins.append(np.repeat(
+                        np.asarray(spec.origins, np.int64), spec.n_trials))
+                    seeds.append(self._entry_seeds(spec, p))
+                    shapes.append((len(spec.origins), spec.n_trials))
+                fused = QuerySpec(
+                    origins=tuple(int(o) for o in np.concatenate(origins)),
+                    n_trials=1, k=k, latency_model=lm, precision=prec,
+                    seeds=np.concatenate(seeds)[:, None])
+                res = self._execute(fused, pol,
+                                    self._effective(fused, params))
+                lo = 0
+                for i, (Q, T) in zip(idxs, shapes):
+                    hi = lo + Q * T
+                    results[i] = dataclasses.replace(
+                        res, metrics=_slice_rows(res.metrics, lo, Q, T),
+                        values=(None if res.values is None else
+                                res.values.reshape(-1, k)[lo:hi]
+                                .reshape(Q, T, k)),
+                        indices=(None if res.indices is None else
+                                 res.indices.reshape(-1, k)[lo:hi]
+                                 .reshape(Q, T, k)),
+                        batch_size=len(idxs), extras=dict(res.extras))
+                    lo += Q * T
+            return results
 
     def _execute(self, spec: QuerySpec, pol: Policy,
                  p: SimParams) -> TopKResult:
         """Run one (already resolved) spec on the prepared overlay."""
         if self.plan is None:
             raise RuntimeError("call SimEngine.prepare(topology) first")
-        if self.plan.overlay is not None:
-            self.plan.sync()              # live overlay: catch up by version
-        _latency_mode(self.plan.top, p)   # validate model name + coords
+        prec = spec.precision or self._precision
         if pol.algorithm == "fd-stats":
-            if (spec.precision or self._precision) != "f64":
+            if prec != "f64":
                 raise ValueError(
                     "fd-stats runs on the scalar reference path, which "
                     "is f64-only; request precision='f64' (or None)")
+            self._sync(p)
             return self._run_stats(spec, pol, p)
-
-        origins = np.atleast_1d(np.asarray(spec.origins, dtype=np.int64))
-        Q, T = len(origins), spec.n_trials
-        ent_seeds = self._entry_seeds(spec, p)
-        prec = spec.precision or self._precision
         if prec != "f64" and self._backend != "jax":
             raise ValueError(
                 f"spec requests precision={prec!r} but the numpy backend "
                 "only runs f64 (it IS the ground truth); use "
                 "SimEngine(backend='jax')")
 
+        origins = np.atleast_1d(np.asarray(spec.origins, dtype=np.int64))
+        Q, T = len(origins), spec.n_trials
+        ent_seeds = self._entry_seeds(spec, p)
         fw_strategy = ("basic" if pol.algorithm in ("cn", "cn_star")
                        else pol.strategy)
-        n_statics = len(self.plan._statics)
-        t0 = time.perf_counter()
-        sts, st_of_q = self.plan.origin_statics(origins, p.ttl, fw_strategy)
-        # statics wall counts as compile only when this call actually
-        # BUILT something — a warm plan reports 0.0, so serving-layer
-        # assertions on "no compile on the steady path" hold
-        compile_s = (time.perf_counter() - t0
-                     if len(self.plan._statics) > n_statics else 0.0)
+        with TraceAnnotation("fd.engine.statics") as span:
+            self._sync(p)
+            n_statics = len(self.plan._statics)
+            t0 = time.perf_counter()
+            sts, st_of_q = self.plan.origin_statics(origins, p.ttl,
+                                                    fw_strategy)
+            # statics wall counts as compile only when this call
+            # actually BUILT something — a warm plan reports 0.0, so
+            # serving-layer assertions on "no compile on the steady
+            # path" hold
+            built = len(self.plan._statics) - n_statics
+            compile_s = time.perf_counter() - t0 if built else 0.0
+            # replica placement is retrieval-phase only (FD paths); the
+            # CN baselines never enter the owner-fetch fallback
+            rep = (None if pol.algorithm in ("cn", "cn_star")
+                   else self.plan.replica_table(p))
+            span.set_metadata(built=built)
         ent_st = np.repeat(st_of_q, T)
         ent_origin = np.repeat(origins, T)
-        # replica placement is retrieval-phase only (FD paths); the CN
-        # baselines never enter the owner-fetch fallback
-        rep = (None if pol.algorithm in ("cn", "cn_star")
-               else self.plan.replica_table(p))
         extras: dict = {}
         t0 = time.perf_counter()
         if self._backend == "jax":
@@ -376,6 +383,13 @@ class SimEngine(Engine):
                                    else owns.reshape(Q, T, p.k)),
                           compile_s=compile_s, run_s=run_s,
                           extras=extras)
+
+    def _sync(self, p: SimParams) -> None:
+        """Catch a live overlay's plan up by version; validate the
+        latency model's name and coordinates."""
+        if self.plan.overlay is not None:
+            self.plan.sync()
+        _latency_mode(self.plan.top, p)
 
     # ---- statistics heuristic (paper §3.3 + Fig 7) ----------------------
 

@@ -94,6 +94,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import jaxcompat
 from repro.engine.plan import DepthSlices, NetworkPlan
@@ -476,6 +477,12 @@ def _device_slices(sl: DepthSlices):
     return cached + (rr,)
 
 
+def _to_host(x, nbytes: list) -> np.ndarray:
+    """Copy a device array to the host, noting its size in ``nbytes``."""
+    nbytes.append(x.nbytes)
+    return np.asarray(x)
+
+
 def _cache_entries(fn) -> int:
     """Size of a jitted function's trace cache (-1 when unknowable)."""
     try:
@@ -550,10 +557,11 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
     # latency_model="edge": the embedding-derived latencies enter here
     # (inside up_term / dn_term / lat_o, same draws as the numpy
     # backend), so the jitted sweeps need no edge-vs-iid branch at all
-    par_lat, origin_lat = _entry_latencies(sts, ent_st, p)
-    draws = _precompute_draws(ent_origin, seeds, n, p, algorithm,
-                              sts[0].fw_strategy, lifetime_mean_s,
-                              independent, par_lat, origin_lat)
+    with TraceAnnotation("fd.engine.draws", entries=E):
+        par_lat, origin_lat = _entry_latencies(sts, ent_st, p)
+        draws = _precompute_draws(ent_origin, seeds, n, p, algorithm,
+                                  sts[0].fw_strategy, lifetime_mean_s,
+                                  independent, par_lat, origin_lat)
     out = _empty_out(E, k)
     out["jax_compile_s"] = 0.0
     out["jax_traces"] = 0
@@ -576,15 +584,21 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
         shard = False
 
     def _timed(fn, *args, **kw):
-        """Call a jitted sweep; attribute its wall time to compile when
+        """Call a jitted sweep, its upload included, through
+        ``block_until_ready``; attribute its wall time to compile when
         the call actually traced (jit cache grew)."""
-        before = _cache_entries(fn)
-        t0 = time.perf_counter()
-        res = fn(*args, **kw)
-        jax.block_until_ready(res)
-        wall = time.perf_counter() - t0
-        after = _cache_entries(fn)
-        if after > before >= 0:
+        h2d = sum(a.nbytes for a in args if isinstance(a, np.ndarray))
+        with TraceAnnotation("fd.engine.sweep", rows=len(args[0]),
+                             h2d_bytes=h2d) as span:
+            before = _cache_entries(fn)
+            t0 = time.perf_counter()
+            res = fn(*args, **kw)
+            jax.block_until_ready(res)
+            wall = time.perf_counter() - t0
+            after = _cache_entries(fn)
+            traced = after > before >= 0
+            span.set_metadata(traced=int(traced))
+        if traced:
             out["jax_compile_s"] += wall
             out["jax_traces"] += after - before
         return res
@@ -599,16 +613,25 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
                 es = ent_of_st[si]
                 es_run, full = _pad_group(es, E, 1)
                 m = len(es)
-                sl = plan.depth_slices(st)
-                levels, _, _ = _device_slices(sl)
-                te = draws.t_exec if full else draws.t_exec[es_run]
-                dn = draws.dn_term if full else draws.dn_term[es_run]
-                ted = _timed(_cn_sweep, cast(te), cast(dn), levels)
-                for d, lv in enumerate(sl.levels):
-                    t_ex_done[np.ix_(es, lv["vv"])] = \
-                        np.asarray(ted[d])[:m]
-        _cn_entries(out, draws, sts, ent_st, ent_origin, t_ex_done, p,
-                    algorithm)
+                with TraceAnnotation("fd.engine.stage", entries=m,
+                                     rows=len(es_run)):
+                    sl = plan.depth_slices(st)
+                    levels, _, _ = _device_slices(sl)
+                    te = cast(draws.t_exec if full
+                              else draws.t_exec[es_run])
+                    dn = cast(draws.dn_term if full
+                              else draws.dn_term[es_run])
+                ted = _timed(_cn_sweep, te, dn, levels)
+                with TraceAnnotation("fd.engine.copy_back") as span:
+                    copied: list = []
+                    for d, lv in enumerate(sl.levels):
+                        t_ex_done[np.ix_(es, lv["vv"])] = \
+                            _to_host(ted[d], copied)[:m]
+                    span.set_metadata(transfers=len(copied),
+                                      d2h_bytes=sum(copied))
+        with TraceAnnotation("fd.engine.epilogue"):
+            _cn_entries(out, draws, sts, ent_st, ent_origin, t_ex_done, p,
+                        algorithm)
         return out
 
     # ---- FD: jitted forward + merge sweeps per origin -------------------
@@ -623,19 +646,26 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
             es = ent_of_st[si]
             m = len(es)
             es_run, full = _pad_group(es, E, n_dev)
-            sl = plan.depth_slices(st, reroute=with_reroute)
-            levels, els, rr = _device_slices(sl)
             with_st1 = st.fw_strategy != "basic"
 
             def _take(a):
                 return a if full else a[es_run]
-            tqf = lam = cast(np.zeros(0))
-            if with_st1:
-                tqf = cast(np.where(st.depth >= 0,
-                                    st.depth * p.t_qsnd_s, np.inf))
-                lam = cast(_take(draws.lam))
-            death = cast(_take(draws.death)) if churn else cast(
-                np.zeros(0))
+            with TraceAnnotation("fd.engine.stage", entries=m,
+                                 rows=len(es_run)):
+                sl = plan.depth_slices(st, reroute=with_reroute)
+                levels, els, rr = _device_slices(sl)
+                tqf = lam = cast(np.zeros(0))
+                if with_st1:
+                    tqf = cast(np.where(st.depth >= 0,
+                                        st.depth * p.t_qsnd_s, np.inf))
+                    lam = cast(_take(draws.lam))
+                death = cast(_take(draws.death)) if churn else cast(
+                    np.zeros(0))
+                scores = cast(_take(draws.scores))
+                t_exec = cast(_take(draws.t_exec))
+                up_term = cast(_take(draws.up_term))
+                dn_term = cast(_take(draws.dn_term))
+                wt = cast(wait_time(st.ttl_rem, p))
             if shard:
                 fd = _sharded_fd_sweep(n_dev, k, use_pallas,
                                        with_st1, churn, with_reroute)
@@ -646,96 +676,103 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
                           with_st1=with_st1, with_churn=churn,
                           with_reroute=with_reroute)
             send_d, arr_d, mv_d, mo_d, skip, alive_d = _timed(
-                fd, cast(_take(draws.scores)), cast(_take(draws.t_exec)),
-                cast(_take(draws.up_term)), cast(_take(draws.dn_term)),
-                death, cast(wait_time(st.ttl_rem, p)), tqf, lam,
+                fd, scores, t_exec, up_term, dn_term, death, wt, tqf, lam,
                 levels, els, rr if with_reroute else None, **kw)
-            for d, lv in enumerate(sl.levels):
-                rows = np.ix_(es, lv["vv"])
-                send_t[rows] = np.asarray(send_d[d])[:m]
-                if d:
-                    arr_t[rows] = np.asarray(arr_d[d])[:m]
-                mvals[rows] = np.asarray(mv_d[d])[:m]
-                mown[rows] = np.asarray(mo_d[d])[:m]
+            with TraceAnnotation("fd.engine.copy_back") as span:
+                copied: list = []
+                for d, lv in enumerate(sl.levels):
+                    rows = np.ix_(es, lv["vv"])
+                    send_t[rows] = _to_host(send_d[d], copied)[:m]
+                    if d:
+                        arr_t[rows] = _to_host(arr_d[d], copied)[:m]
+                    mvals[rows] = _to_host(mv_d[d], copied)[:m]
+                    mown[rows] = _to_host(mo_d[d], copied)[:m]
+                    if churn:
+                        valid[rows] = _to_host(alive_d[d], copied)[:m]
+                out["m_fw"][es] = (
+                    st.fw_static + sl.n_els
+                    - np.asarray(_to_host(skip, copied), np.int64)[:m]
+                    if with_st1 else st.m_basic)
+                span.set_metadata(transfers=len(copied),
+                                  d2h_bytes=sum(copied))
+
+    with TraceAnnotation("fd.engine.epilogue"):
+        # every reached peer that is still alive at its send time sends its
+        # list exactly once (without churn that is everyone but the origin)
+        if churn:
+            for si, st in enumerate(sts):
+                es = ent_of_st[si]
+                n_alive = valid[np.ix_(es, st.idx)].sum(axis=1)
+                out["m_bw"][es] += n_alive - 1        # origin never dies
+                out["b_bw"][es] += (n_alive - 1) * list_bytes
+        else:
+            n_reached_arr = np.array([len(st.idx) for st in sts], np.int64)
+            out["m_bw"] += n_reached_arr[ent_st] - 1
+            out["b_bw"] += (n_reached_arr[ent_st] - 1) * list_bytes
+
+        # ---- urgent lists (§4.1): late-arrival post-pass ----------------
+        urgent: list = [[] for _ in range(E)]
+        if dynamic:
+            hop_term = p.latency_mean_s + list_bytes / p.bw_mean_Bps
+            for si, st in enumerate(sts):
+                es = ent_of_st[si]
+                ch = st.kid_sorted
+                if len(ch) == 0:
+                    continue
+                pr = st.parent[ch]
+                a = arr_t[np.ix_(es, ch)]
+                late = a > send_t[np.ix_(es, pr)]
                 if churn:
-                    valid[rows] = np.asarray(alive_d[d])[:m]
-            out["m_fw"][es] = (st.fw_static + sl.n_els
-                               - np.asarray(skip, np.int64)[:m]
-                               if with_st1 else st.m_basic)
+                    # a dead child never went urgent; a dead parent's
+                    # children reroute (counted below) instead
+                    late &= valid[np.ix_(es, ch)] & valid[np.ix_(es, pr)]
+                if not late.any():
+                    continue
+                d_par = st.depth[pr]
+                ei, ci = np.nonzero(late)
+                etas = a[ei, ci] + d_par[ci] * hop_term
+                for e_, c_, eta in zip(es[ei], ch[ci], etas):
+                    urgent[int(e_)].append((eta, int(c_)))
+                out["m_bw"][es] += (late * d_par[None, :]).sum(axis=1)
+                out["b_bw"][es] += (
+                    late * (d_par[None, :] * list_bytes)).sum(axis=1)
 
-    # every reached peer that is still alive at its send time sends its
-    # list exactly once (without churn that is everyone but the origin)
-    if churn:
-        for si, st in enumerate(sts):
-            es = ent_of_st[si]
-            n_alive = valid[np.ix_(es, st.idx)].sum(axis=1)
-            out["m_bw"][es] += n_alive - 1        # origin never dies
-            out["b_bw"][es] += (n_alive - 1) * list_bytes
-    else:
-        n_reached_arr = np.array([len(st.idx) for st in sts], np.int64)
-        out["m_bw"] += n_reached_arr[ent_st] - 1
-        out["b_bw"] += (n_reached_arr[ent_st] - 1) * list_bytes
+        # ---- §4.2 reroute accounting: one message per accepted list -----
+        if with_reroute:
+            for si, st in enumerate(sts):
+                es = ent_of_st[si]
+                cnt = _reroute_counts(st, valid[es])
+                out["m_bw"][es] += cnt
+                out["b_bw"][es] += cnt * list_bytes
 
-    # ---- urgent lists (§4.1): late-arrival post-pass --------------------
-    urgent: list = [[] for _ in range(E)]
-    if dynamic:
-        hop_term = p.latency_mean_s + list_bytes / p.bw_mean_Bps
-        for si, st in enumerate(sts):
-            es = ent_of_st[si]
-            ch = st.kid_sorted
-            if len(ch) == 0:
-                continue
-            pr = st.parent[ch]
-            a = arr_t[np.ix_(es, ch)]
-            late = a > send_t[np.ix_(es, pr)]
-            if churn:
-                # a dead child never went urgent; a dead parent's
-                # children reroute (counted below) instead
-                late &= valid[np.ix_(es, ch)] & valid[np.ix_(es, pr)]
-            if not late.any():
-                continue
-            d_par = st.depth[pr]
-            ei, ci = np.nonzero(late)
-            etas = a[ei, ci] + d_par[ci] * hop_term
-            for e_, c_, eta in zip(es[ei], ch[ci], etas):
-                urgent[int(e_)].append((eta, int(c_)))
-            out["m_bw"][es] += (late * d_par[None, :]).sum(axis=1)
-            out["b_bw"][es] += (late
-                                * (d_par[None, :] * list_bytes)).sum(axis=1)
-
-    # ---- §4.2 reroute accounting: one message per accepted list ---------
-    if with_reroute:
-        for si, st in enumerate(sts):
-            es = ent_of_st[si]
-            cnt = _reroute_counts(st, valid[es])
-            out["m_bw"][es] += cnt
-            out["b_bw"][es] += cnt * list_bytes
-
-    # ground truth from the scores AS THE SWEEP SAW THEM (cast once,
-    # compared in f64 — the upcast is exact): reduced-precision runs
-    # must value-match the retrieval epilogue against cast scores, and
-    # in f64 this is the identical array
-    truth_scores = (draws.scores if fp64
-                    else cast(draws.scores).astype(np.float64))
-    top_true_all = _true_topk_by_origin(truth_scores, sts, ent_of_st, k)
-    if fp64:
-        # a device that emulates float64 (TPU: about 49 significant
-        # bits) rounds the scores on upload, and the sweep returns them
-        # so rounded; round the truth the same way so the epilogue's
-        # exact value matching sees what the sweep saw (a no-op where
-        # float64 is native)
-        with x64():
-            top_true_all = np.asarray(jax.device_put(top_true_all))
-    t_merge_done = send_t[np.arange(E), ent_origin] + p.merge_s
-    _accept_urgent_origin(urgent, ent_origin, t_merge_done, mvals, mown,
-                          valid, k)
-    ar = np.arange(E)
-    out["values"] = mvals[ar, ent_origin]
-    out["owners"] = mown[ar, ent_origin].astype(np.int64)
-    if draws.exact:
-        _retrieval_exact(out, draws, ent_origin, t_merge_done, mvals,
-                         mown, top_true_all, p, replicas)
-    else:
-        _retrieval_shared(out, draws, ent_origin, t_merge_done, mvals,
-                          mown, top_true_all, p, replicas)
+        # ground truth from the scores AS THE SWEEP SAW THEM (cast once,
+        # compared in f64 — the upcast is exact): reduced-precision runs
+        # must value-match the retrieval epilogue against cast scores, and
+        # in f64 this is the identical array
+        with TraceAnnotation("fd.engine.truth", entries=E):
+            truth_scores = (draws.scores if fp64
+                            else cast(draws.scores).astype(np.float64))
+            top_true_all = _true_topk_by_origin(truth_scores, sts,
+                                                ent_of_st, k)
+            if fp64:
+                # a device that emulates float64 (TPU: about 49
+                # significant bits) rounds the scores on upload, and the
+                # sweep returns them so rounded; round the truth the same
+                # way so the epilogue's exact value matching sees what
+                # the sweep saw (a no-op where float64 is native)
+                with x64():
+                    top_true_all = np.asarray(jax.device_put(top_true_all))
+        t_merge_done = send_t[np.arange(E), ent_origin] + p.merge_s
+        _accept_urgent_origin(urgent, ent_origin, t_merge_done, mvals, mown,
+                              valid, k)
+        ar = np.arange(E)
+        out["values"] = mvals[ar, ent_origin]
+        out["owners"] = mown[ar, ent_origin].astype(np.int64)
+        with TraceAnnotation("fd.engine.retrieval", entries=E):
+            if draws.exact:
+                _retrieval_exact(out, draws, ent_origin, t_merge_done,
+                                 mvals, mown, top_true_all, p, replicas)
+            else:
+                _retrieval_shared(out, draws, ent_origin, t_merge_done,
+                                  mvals, mown, top_true_all, p, replicas)
     return out
