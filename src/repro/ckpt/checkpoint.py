@@ -112,7 +112,7 @@ class CheckpointManager:
         self.wait()
         self._thread = save(self.directory, step, tree,
                             blocking=self.blocking)
-        self._gc()
+        self._gc(step)
         return True
 
     def wait(self):
@@ -120,14 +120,16 @@ class CheckpointManager:
             self._thread.join()
             self._thread = None
 
-    def _gc(self):
-        # called right after a new write STARTED: keep (keep-1) existing
-        # checkpoints so the in-flight one completes the keep-N set
+    def _gc(self, writing: int):
+        # called right after the write of step ``writing`` STARTED: keep
+        # (keep-1) other checkpoints so that write completes the keep-N
+        # set, whether or not it has already finished
         if not os.path.isdir(self.directory) or not self.keep:
             return
         steps = sorted(s for s in (
             int(n[5:]) for n in os.listdir(self.directory)
-            if n.startswith("step_") and not n.endswith(".tmp")))
+            if n.startswith("step_") and not n.endswith(".tmp"))
+            if s != writing)
         cut = max(self.keep - 1, 1)
         for s in steps[:-cut]:
             shutil.rmtree(os.path.join(

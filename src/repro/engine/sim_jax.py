@@ -80,8 +80,14 @@ same jitted sweep — no numpy fallback:
     run time whether it contributes — fixed-shape gather/select, like
     everything else here;
   * urgent-list forwarding (§4.1) and the reroute message accounting
-    stay in the shared numpy epilogue, computed from the per-level
-    ``alive`` masks the sweep returns.
+    stay in the shared numpy epilogue, computed from the ``alive`` masks
+    the sweep returns.
+
+Copy back: only what that epilogue reads leaves the device — the send,
+list-arrival and liveness rows of the reached peers, packed in level
+order, and each origin's merged list.  Every peer's k-list stays on the
+device; the few the origin folds in as accepted urgent lists are
+gathered on demand (``_urgent_rows``).
 """
 from __future__ import annotations
 
@@ -109,7 +115,8 @@ from repro.p2psim.simulate import (SimParams, _accept_urgent_origin,
                                    _entry_latencies, _precompute_draws,
                                    _reroute_counts, _retrieval_exact,
                                    _retrieval_shared,
-                                   _true_topk_by_origin, wait_time)
+                                   _true_topk_by_origin, _urgent_accepted,
+                                   wait_time)
 
 
 def _merge_desc(va, ia, vb, ib, valid_a=None, valid_b=None):
@@ -272,6 +279,15 @@ def _fd_sweep_impl(scores, t_exec, up_term, dn_term, death, wt, tqf, lam,
     re-adding them in its own arithmetic (which differs from an
     emulated device float64 in the last bits, and would turn every
     child that released its parent into a phantom late arrival).
+
+    Returns ``(send, arr, alive, origin_v, origin_o, skip, m_v, m_o)``:
+    the send times, list-arrival times (levels 1 and deeper; the origin
+    sends no list) and, under churn, liveness of every reached peer,
+    each (E, reached) packed along the peer axis in level order
+    (``_level_order``); the origin's merged list (E, k); the Strategy-1
+    skip counts (or None); and every level's merged lists (E, L, k).
+    The host copies back only the packed rows and the origin's list;
+    the level lists stay on the device for ``_urgent_rows``.
     """
     E = t_exec.shape[0]
     K = _next_pow2(k)
@@ -372,9 +388,13 @@ def _fd_sweep_impl(scores, t_exec, up_term, dn_term, death, wt, tqf, lam,
             m_v[d], m_o[d] = mv, mo
         if d:
             arr[d] = send[d] + up_term[:, vv]
-    return (tuple(send), tuple(arr), tuple(v[:, :, :k] for v in m_v),
-            tuple(o[:, :, :k] for o in m_o), skip,
-            tuple(alive) if with_churn else None)
+    m_v = tuple(v[:, :, :k] for v in m_v)
+    m_o = tuple(o[:, :, :k] for o in m_o)
+    arr_p = (jnp.concatenate(arr[1:], axis=1) if dmax
+             else jnp.zeros((E, 0), send[0].dtype))
+    return (jnp.concatenate(send, axis=1), arr_p,
+            jnp.concatenate(alive, axis=1) if with_churn else None,
+            m_v[0][:, 0], m_o[0][:, 0], skip, m_v, m_o)
 
 
 _SWEEP_STATICS = ("k", "use_pallas", "with_st1", "with_churn",
@@ -442,6 +462,29 @@ def _cn_sweep(t_exec, dn_term, levels):
                  for tq, lv in zip(t_qs, levels))
 
 
+URGENT_CHUNK = 16          # rows per ``_urgent_rows`` call (fixed shape)
+
+
+@jax.jit
+def _urgent_rows(m_v, m_o, lvl, pos, row):
+    """The merged lists of accepted urgent children, gathered from one
+    sweep's device-resident level outputs ``m_v`` / ``m_o``.
+
+    Row i is the list at level ``lvl[i]``, position ``pos[i]`` inside
+    that level, entry row ``row[i]``; ``lvl`` -1 pads (-inf / -1 rows).
+    The index has a fixed length (``URGENT_CHUNK``), so one program
+    serves every call on the same outputs' shapes.
+    """
+    v = jnp.full(lvl.shape + m_v[0].shape[-1:], -jnp.inf, m_v[0].dtype)
+    o = jnp.full(v.shape, -1, m_o[0].dtype)
+    for d in range(1, len(m_v)):         # level 0 is the origin itself
+        hit = (lvl == d)[:, None]
+        p = jnp.minimum(pos, m_v[d].shape[1] - 1)
+        v = jnp.where(hit, m_v[d][row, p], v)
+        o = jnp.where(hit, m_o[d][row, p], o)
+    return v, o
+
+
 def _conv_slice_field(f, v):
     if f.endswith("rounds"):
         return tuple(tuple(jnp.asarray(x) for x in rnd) for rnd in v)
@@ -477,6 +520,20 @@ def _device_slices(sl: DepthSlices):
     return cached + (rr,)
 
 
+def _level_order(sl: DepthSlices):
+    """The sweep's packed peer order (levels in turn, each ascending)
+    and every reached peer's position inside its own level, cached on
+    the slices beside their device copy."""
+    cached = getattr(sl, "_order", None)
+    if cached is None:
+        order = np.concatenate([lv["vv"] for lv in sl.levels])
+        pos = np.zeros(sl.n, np.int32)
+        for lv in sl.levels:
+            pos[lv["vv"]] = np.arange(len(lv["vv"]))
+        cached = sl._order = (order, pos)
+    return cached
+
+
 def _to_host(x, nbytes: list) -> np.ndarray:
     """Copy a device array to the host, noting its size in ``nbytes``."""
     nbytes.append(x.nbytes)
@@ -489,6 +546,24 @@ def _cache_entries(fn) -> int:
         return fn._cache_size()
     except Exception:
         return -1
+
+
+def _compiled(out: dict, fn, *args, **kw):
+    """Call a jitted program through ``block_until_ready``; attribute
+    its wall time to ``out["jax_compile_s"]`` when the call actually
+    traced (jit cache grew).  Returns the result and whether it
+    traced."""
+    before = _cache_entries(fn)
+    t0 = time.perf_counter()
+    res = fn(*args, **kw)
+    jax.block_until_ready(res)
+    wall = time.perf_counter() - t0
+    after = _cache_entries(fn)
+    traced = after > before >= 0
+    if traced:
+        out["jax_compile_s"] += wall
+        out["jax_traces"] += after - before
+    return res, traced
 
 
 def _pad_group(es: np.ndarray, E: int, n_dev: int):
@@ -584,23 +659,12 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
         shard = False
 
     def _timed(fn, *args, **kw):
-        """Call a jitted sweep, its upload included, through
-        ``block_until_ready``; attribute its wall time to compile when
-        the call actually traced (jit cache grew)."""
+        """A jitted sweep, its upload included, inside its span."""
         h2d = sum(a.nbytes for a in args if isinstance(a, np.ndarray))
         with TraceAnnotation("fd.engine.sweep", rows=len(args[0]),
                              h2d_bytes=h2d) as span:
-            before = _cache_entries(fn)
-            t0 = time.perf_counter()
-            res = fn(*args, **kw)
-            jax.block_until_ready(res)
-            wall = time.perf_counter() - t0
-            after = _cache_entries(fn)
-            traced = after > before >= 0
+            res, traced = _compiled(out, fn, *args, **kw)
             span.set_metadata(traced=int(traced))
-        if traced:
-            out["jax_compile_s"] += wall
-            out["jax_traces"] += after - before
         return res
 
     # ---- CN / CN*: arrival sweep on device, baseline math shared --------
@@ -635,12 +699,17 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
         return out
 
     # ---- FD: jitted forward + merge sweeps per origin -------------------
+    # Only what the epilogue reads leaves the device: the packed send,
+    # arrival and liveness rows, the origin's merged list, and the lists
+    # of the urgent children the origin accepts (``_urgent_rows``).
     with_reroute = churn and dynamic
     send_t = np.full((E, n), np.inf)
     arr_t = np.full((E, n), np.inf)      # list arrival at the parent
-    mvals = np.empty((E, n, k))
-    mown = np.full((E, n, k), -1, np.int32)
     valid = np.zeros((E, n), bool) if churn else None
+    org_v = np.full((E, k), -np.inf)     # the origin's merged list
+    org_o = np.full((E, k), -1, np.int32)
+    t_merge_done = np.empty(E)
+    hop_term = p.latency_mean_s + list_bytes / p.bw_mean_Bps
     with x64():
         for si, st in enumerate(sts):
             es = ent_of_st[si]
@@ -675,26 +744,39 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
                 kw = dict(k=k, use_pallas=use_pallas,
                           with_st1=with_st1, with_churn=churn,
                           with_reroute=with_reroute)
-            send_d, arr_d, mv_d, mo_d, skip, alive_d = _timed(
+            send_d, arr_d, alive_d, ov_d, oo_d, skip, mv_d, mo_d = _timed(
                 fd, scores, t_exec, up_term, dn_term, death, wt, tqf, lam,
                 levels, els, rr if with_reroute else None, **kw)
             with TraceAnnotation("fd.engine.copy_back") as span:
                 copied: list = []
-                for d, lv in enumerate(sl.levels):
-                    rows = np.ix_(es, lv["vv"])
-                    send_t[rows] = _to_host(send_d[d], copied)[:m]
-                    if d:
-                        arr_t[rows] = _to_host(arr_d[d], copied)[:m]
-                    mvals[rows] = _to_host(mv_d[d], copied)[:m]
-                    mown[rows] = _to_host(mo_d[d], copied)[:m]
-                    if churn:
-                        valid[rows] = _to_host(alive_d[d], copied)[:m]
+                order, pos_of = _level_order(sl)
+                send_t[np.ix_(es, order)] = _to_host(send_d, copied)[:m]
+                arr_t[np.ix_(es, order[1:])] = _to_host(arr_d, copied)[:m]
+                if churn:
+                    valid[np.ix_(es, order)] = _to_host(alive_d,
+                                                        copied)[:m]
+                org_v[es] = _to_host(ov_d, copied)[:m]
+                org_o[es] = _to_host(oo_d, copied)[:m]
                 out["m_fw"][es] = (
                     st.fw_static + sl.n_els
                     - np.asarray(_to_host(skip, copied), np.int64)[:m]
                     if with_st1 else st.m_basic)
+                t_merge_done[es] = send_t[es, ent_origin[es]] + p.merge_s
+                n_urgent = 0
+                if dynamic:
+                    ue, uc = _urgent_pass(out, st, es, ent_origin, send_t,
+                                          arr_t, valid, t_merge_done,
+                                          hop_term, list_bytes)
+                    n_urgent = len(uc)
+                    # called with no rows too, so that warming a sweep
+                    # compiles its gather
+                    ei = np.searchsorted(es, ue)
+                    cv, co = _fetch_rows(out, mv_d, mo_d, st.depth[uc],
+                                         pos_of[uc], ei, copied)
+                    _accept_urgent_origin(org_v, org_o, ue, cv, co, k)
                 span.set_metadata(transfers=len(copied),
-                                  d2h_bytes=sum(copied))
+                                  d2h_bytes=sum(copied),
+                                  urgent_rows=n_urgent)
 
     with TraceAnnotation("fd.engine.epilogue"):
         # every reached peer that is still alive at its send time sends its
@@ -709,33 +791,6 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
             n_reached_arr = np.array([len(st.idx) for st in sts], np.int64)
             out["m_bw"] += n_reached_arr[ent_st] - 1
             out["b_bw"] += (n_reached_arr[ent_st] - 1) * list_bytes
-
-        # ---- urgent lists (§4.1): late-arrival post-pass ----------------
-        urgent: list = [[] for _ in range(E)]
-        if dynamic:
-            hop_term = p.latency_mean_s + list_bytes / p.bw_mean_Bps
-            for si, st in enumerate(sts):
-                es = ent_of_st[si]
-                ch = st.kid_sorted
-                if len(ch) == 0:
-                    continue
-                pr = st.parent[ch]
-                a = arr_t[np.ix_(es, ch)]
-                late = a > send_t[np.ix_(es, pr)]
-                if churn:
-                    # a dead child never went urgent; a dead parent's
-                    # children reroute (counted below) instead
-                    late &= valid[np.ix_(es, ch)] & valid[np.ix_(es, pr)]
-                if not late.any():
-                    continue
-                d_par = st.depth[pr]
-                ei, ci = np.nonzero(late)
-                etas = a[ei, ci] + d_par[ci] * hop_term
-                for e_, c_, eta in zip(es[ei], ch[ci], etas):
-                    urgent[int(e_)].append((eta, int(c_)))
-                out["m_bw"][es] += (late * d_par[None, :]).sum(axis=1)
-                out["b_bw"][es] += (
-                    late * (d_par[None, :] * list_bytes)).sum(axis=1)
 
         # ---- §4.2 reroute accounting: one message per accepted list -----
         if with_reroute:
@@ -762,17 +817,67 @@ def run_entries_jax(plan: NetworkPlan, sts, ent_st: np.ndarray,
                 # the sweep saw (a no-op where float64 is native)
                 with x64():
                     top_true_all = np.asarray(jax.device_put(top_true_all))
-        t_merge_done = send_t[np.arange(E), ent_origin] + p.merge_s
-        _accept_urgent_origin(urgent, ent_origin, t_merge_done, mvals, mown,
-                              valid, k)
-        ar = np.arange(E)
-        out["values"] = mvals[ar, ent_origin]
-        out["owners"] = mown[ar, ent_origin].astype(np.int64)
+        out["values"] = org_v
+        out["owners"] = org_o.astype(np.int64)
         with TraceAnnotation("fd.engine.retrieval", entries=E):
-            if draws.exact:
-                _retrieval_exact(out, draws, ent_origin, t_merge_done,
-                                 mvals, mown, top_true_all, p, replicas)
-            else:
-                _retrieval_shared(out, draws, ent_origin, t_merge_done,
-                                  mvals, mown, top_true_all, p, replicas)
+            retrieval = (_retrieval_exact if draws.exact
+                         else _retrieval_shared)
+            retrieval(out, draws, t_merge_done, org_v, org_o,
+                      top_true_all, p, replicas)
     return out
+
+
+def _urgent_pass(out: dict, st, es: np.ndarray, ent_origin: np.ndarray,
+                 send_t: np.ndarray, arr_t: np.ndarray,
+                 valid: Optional[np.ndarray], t_merge_done: np.ndarray,
+                 hop_term: float, list_bytes: int):
+    """Urgent lists (§4.1) of one origin's entries ``es``: count the
+    messages of every late child into ``out`` and return the (entry,
+    child) pairs whose lists the origin accepts, entry by entry in
+    child order — the late-arrival post-pass on the copied send and
+    arrival times."""
+    none = np.zeros(0, np.int64)
+    ch = st.kid_sorted
+    if len(ch) == 0:
+        return none, none
+    pr = st.parent[ch]
+    a = arr_t[np.ix_(es, ch)]
+    late = a > send_t[np.ix_(es, pr)]
+    if valid is not None:
+        # a dead child never went urgent; a dead parent's children
+        # reroute (counted in the epilogue) instead
+        late &= valid[np.ix_(es, ch)] & valid[np.ix_(es, pr)]
+    if not late.any():
+        return none, none
+    d_par = st.depth[pr]
+    ei, ci = np.nonzero(late)
+    etas = a[ei, ci] + d_par[ci] * hop_term
+    out["m_bw"][es] += (late * d_par[None, :]).sum(axis=1)
+    out["b_bw"][es] += (late * (d_par[None, :] * list_bytes)).sum(axis=1)
+    ue, uc = es[ei], ch[ci]
+    ok = _urgent_accepted(ue, uc, etas, ent_origin, t_merge_done, valid)
+    return ue[ok], uc[ok]
+
+
+def _fetch_rows(out: dict, m_v, m_o, lvl, pos, row, copied: list):
+    """Copy the lists at (level ``lvl``, position ``pos``, entry row
+    ``row``) of one sweep's device-resident outputs to the host, in
+    fixed chunks of ``URGENT_CHUNK`` rows through ``_urgent_rows``.
+    Runs the gather once even for no rows (its result then stays on the
+    device).  Returns float64 values and int32 owners."""
+    k = m_v[0].shape[-1]
+    vals, owns = [np.empty((0, k))], [np.empty((0, k), np.int32)]
+    for c0 in range(0, max(len(row), 1), URGENT_CHUNK):
+        part = slice(c0, c0 + URGENT_CHUNK)
+        got = len(row[part])
+        pad = (0, URGENT_CHUNK - got)
+        (v, o), _ = _compiled(
+            out, _urgent_rows, m_v, m_o,
+            np.pad(lvl[part], pad, constant_values=-1).astype(np.int32),
+            np.pad(pos[part], pad).astype(np.int32),
+            np.pad(row[part], pad).astype(np.int32))
+        if got:
+            vals.append(_to_host(v, copied)[:got])
+            owns.append(_to_host(o, copied)[:got])
+    return (np.concatenate(vals).astype(np.float64, copy=False),
+            np.concatenate(owns))

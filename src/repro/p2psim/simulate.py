@@ -1314,7 +1314,8 @@ def _run_entries(sts, ent_st: np.ndarray, ent_origin: np.ndarray,
     # before any reader (parent / origin gather) — no init needed
     mvals = np.empty((E, n, k))
     mown = np.empty((E, n, k), np.int32)
-    urgent: list = [[] for _ in range(E)]      # per entry: (eta, peer)
+    # urgent lists as found: entry, late child, due time at the origin
+    urgent_e, urgent_c, urgent_eta = [], [], []
     m_bw = out["m_bw"]
     b_bw = out["b_bw"]
     up_term = draws.up_term                    # arrival link time per node
@@ -1407,8 +1408,9 @@ def _run_entries(sts, ent_st: np.ndarray, ent_origin: np.ndarray,
                 if len(ri):
                     etas = a[ri, ci] + d * (p.latency_mean_s
                                             + list_bytes / p.bw_mean_Bps)
-                    for r_, c_, eta in zip(ri, C[ri, ci], etas):
-                        urgent[int(eeb[r_])].append((eta, int(c_)))
+                    urgent_e.append(eeb[ri])
+                    urgent_c.append(C[ri, ci])
+                    urgent_eta.append(etas)
                     late_cnt = np.bincount(eeb[ri], minlength=E)
                     m_bw += late_cnt * d
                     b_bw += late_cnt * (d * list_bytes)
@@ -1434,18 +1436,22 @@ def _run_entries(sts, ent_st: np.ndarray, ent_origin: np.ndarray,
                     mvals[e_, v_], mown[e_, v_], ev, eo, k)
 
     top_true_all = _true_topk_by_origin(scores, sts, ent_of_st, k)
-    t_merge_done = send_t[np.arange(E), ent_origin] + p.merge_s
-    _accept_urgent_origin(urgent, ent_origin, t_merge_done, mvals, mown,
-                          None if no_churn else valid, k)
     ar = np.arange(E)
-    out["values"] = mvals[ar, ent_origin]
-    out["owners"] = mown[ar, ent_origin].astype(np.int64)
-    if draws.exact:
-        _retrieval_exact(out, draws, ent_origin, t_merge_done, mvals,
-                         mown, top_true_all, p, replicas)
-    else:
-        _retrieval_shared(out, draws, ent_origin, t_merge_done, mvals,
-                          mown, top_true_all, p, replicas)
+    t_merge_done = send_t[ar, ent_origin] + p.merge_s
+    org_v, org_o = mvals[ar, ent_origin], mown[ar, ent_origin]
+    none = np.zeros(0, np.int64)
+    ue = np.concatenate([none] + urgent_e)
+    uc = np.concatenate([none] + urgent_c)
+    eta = np.concatenate([np.zeros(0)] + urgent_eta)
+    ok = _urgent_accepted(ue, uc, eta, ent_origin, t_merge_done,
+                          None if no_churn else valid)
+    ue, uc = ue[ok], uc[ok]
+    _accept_urgent_origin(org_v, org_o, ue, mvals[ue, uc], mown[ue, uc], k)
+    out["values"] = org_v
+    out["owners"] = org_o.astype(np.int64)
+    retrieval = _retrieval_exact if draws.exact else _retrieval_shared
+    retrieval(out, draws, t_merge_done, org_v, org_o, top_true_all, p,
+              replicas)
     return out
 
 
@@ -1544,39 +1550,45 @@ def _reroute_counts(st, valid_rows: np.ndarray) -> np.ndarray:
             & valid_rows[:, gp]).sum(axis=1)
 
 
-def _accept_urgent_origin(urgent, ent_origin: np.ndarray,
-                          t_merge_done: np.ndarray, mvals: np.ndarray,
-                          mown: np.ndarray, valid: Optional[np.ndarray],
+def _urgent_accepted(ue: np.ndarray, uc: np.ndarray, eta: np.ndarray,
+                     ent_origin: np.ndarray, t_merge_done: np.ndarray,
+                     valid: Optional[np.ndarray]) -> np.ndarray:
+    """Which urgent lists the origin accepts (backend-shared): entry
+    ``ue``'s list from child ``uc``, due at the origin at ``eta``, joins
+    when it arrives before the origin's merge is done and both child and
+    origin are alive (``valid`` is None when churn is off)."""
+    ok = eta <= t_merge_done[ue]
+    if valid is not None:
+        ok &= valid[ue, uc] & valid[ue, ent_origin[ue]]
+    return ok
+
+
+def _accept_urgent_origin(org_v: np.ndarray, org_o: np.ndarray,
+                          ue: np.ndarray, cv: np.ndarray, co: np.ndarray,
                           k: int) -> None:
-    """Fold urgent lists arriving before retrieval into the origin's
-    merge (``valid`` is None when churn is off — everyone is alive)."""
-    for e in range(len(ent_origin)):
-        if not urgent[e]:
-            continue
-        origin = int(ent_origin[e])
-        ok = [c for (eta, c) in urgent[e]
-              if eta <= t_merge_done[e]
-              and (valid is None or valid[e, c])]
-        if ok and (valid is None or valid[e, origin]):
-            mvals[e, origin], mown[e, origin] = _topk_remerge(
-                mvals[e, origin], mown[e, origin],
-                [mvals[e, c] for c in ok], [mown[e, c] for c in ok], k)
+    """Fold the accepted urgent lists into the origins' (E, k) merged
+    lists ``org_v`` / ``org_o`` in place: row i of ``cv`` / ``co`` is a
+    list for entry ``ue[i]``, in the order the entry received them."""
+    for e in np.unique(ue):
+        sel = ue == e
+        org_v[e], org_o[e] = _topk_remerge(org_v[e], org_o[e],
+                                           list(cv[sel]), list(co[sel]), k)
 
 
-def _retrieval_exact(out: dict, draws: EntryDraws, ent_origin: np.ndarray,
-                     t_merge_done: np.ndarray, mvals: np.ndarray,
-                     mown: np.ndarray, top_true_all: np.ndarray,
+def _retrieval_exact(out: dict, draws: EntryDraws,
+                     t_merge_done: np.ndarray, org_v: np.ndarray,
+                     org_o: np.ndarray, top_true_all: np.ndarray,
                      p: SimParams, replicas=None) -> None:
-    """run_query's per-entry retrieval, verbatim (bit-for-bit parity).
+    """run_query's per-entry retrieval, verbatim (bit-for-bit parity),
+    from each origin's (E, k) merged list ``org_v`` / ``org_o``.
 
     ``replicas`` — the plan's (n, r) placement table (None = replication
     off): a dead owner's items are served by its first alive replica,
     exactly the scalar reference's fallback."""
     k = p.k
     death, rngs = draws.death, draws.rngs
-    for e in range(len(ent_origin)):
-        origin = int(ent_origin[e])
-        final_owners = np.unique(mown[e, origin])
+    for e in range(len(org_o)):
+        final_owners = np.unique(org_o[e])
         served = _serving_peers(final_owners, replicas, death[e],
                                 t_merge_done[e])
         srv = served >= 0
@@ -1588,7 +1600,7 @@ def _retrieval_exact(out: dict, draws: EntryDraws, ent_origin: np.ndarray,
                 e, np.where(srv, served, final_owners)]
             bw_o = _draw_bw(rngs[e], p, len(final_owners))
         per_owner_counts = np.array(
-            [(mown[e, origin] == o).sum() for o in final_owners])
+            [(org_o[e] == o).sum() for o in final_owners])
         fetch_bytes = per_owner_counts * p.item_mean_B
         out["b_rt"][e] = int(srv.sum() * p.request_B
                              + fetch_bytes[srv].sum())
@@ -1597,33 +1609,32 @@ def _retrieval_exact(out: dict, draws: EntryDraws, ent_origin: np.ndarray,
         out["response_time_s"][e] = float(
             t_merge_done[e] + (t_fetch.max() if len(t_fetch) else 0.0))
 
-        got = mvals[e, origin]              # sorted descending
+        got = org_v[e]                      # sorted descending
         inter = np.intersect1d(top_true_all[e], got).size
-        lost_owned = np.isin(mown[e, origin], final_owners[~srv])
+        lost_owned = np.isin(org_o[e], final_owners[~srv])
         inter = max(0, inter - int(np.isin(
-            mvals[e, origin][lost_owned], top_true_all[e]).sum()))
+            org_v[e][lost_owned], top_true_all[e]).sum()))
         out["accuracy"][e] = inter / k
 
 
 def _retrieval_shared(out: dict, draws: EntryDraws,
-                      ent_origin: np.ndarray, t_merge_done: np.ndarray,
-                      mvals: np.ndarray, mown: np.ndarray,
-                      top_true_all: np.ndarray, p: SimParams,
-                      replicas=None) -> None:
+                      t_merge_done: np.ndarray, org_v: np.ndarray,
+                      org_o: np.ndarray, top_true_all: np.ndarray,
+                      p: SimParams, replicas=None) -> None:
     """Shared-stream fast path: the same retrieval model, vectorized over
     all entries at once (draw assignment to owners differs but is
-    i.i.d. — distributionally identical to the scalar path).
+    i.i.d. — distributionally identical to the scalar path), from each
+    origin's (E, k) merged list ``org_v`` / ``org_o``.
 
     ``replicas`` — (n, r) placement table (None = replication off): a
     dead owner's items are served by its first alive replica.  With
     ``replicas=None`` every expression below reduces bit-for-bit to the
     replication-free code (``served == mo`` wherever it is read)."""
-    E = len(ent_origin)
+    E = len(org_o)
     k = p.k
     death = draws.death
     ar = np.arange(E)
-    mo = mown[ar, ent_origin]                                # (E, k)
-    gv = mvals[ar, ent_origin]                               # (E, k)
+    mo, gv = org_o, org_v                                    # (E, k)
     dth = death[ar[:, None], mo]                             # (E, k)
     alive_elem = dth > t_merge_done[:, None]
     if replicas is None or replicas.shape[1] == 0:

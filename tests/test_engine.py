@@ -170,6 +170,44 @@ def test_jax_urgent_pass_reads_device_arrival_times(name):
     np.testing.assert_array_equal(r32.metrics.m_bw, r64.metrics.m_bw)
 
 
+# link latencies and wait budgets under which late children are common
+# and many of their urgent lists still reach the origin before its merge
+# is done: more than one gather chunk of accepted lists per origin
+URGENT_PA = SimParams(seed=11, latency_mean_s=0.2, latency_var=0.3 ** 2,
+                      t_qsnd_s=0.2, t_slsnd_s=0.3)
+
+
+@pytest.mark.parametrize("name", ["fd-dynamic", "fd-dynamic@25"])
+def test_jax_urgent_rows_bit_exact(name, monkeypatch):
+    """The jax backend copies back only the origin's merged list and
+    fetches the lists of the urgent children the origin accepts from
+    the device, in chunks; the numpy backend reads the same rows from
+    its full arrays.  Both fold them in through the one shared epilogue
+    and agree bit for bit, in both rng modes, under churn and reroute
+    too, with several chunks per origin."""
+    from repro.engine import sim_jax
+    base, _, life = name.partition("@")
+    pol = get_policy(base)
+    if life:
+        pol = pol.variant(lifetime_mean_s=float(life))
+    rows = []
+    accept = sim_jax._accept_urgent_origin
+
+    def spy(org_v, org_o, ue, cv, co, k):
+        rows.append(len(ue))
+        accept(org_v, org_o, ue, cv, co, k)
+    monkeypatch.setattr(sim_jax, "_accept_urgent_origin", spy)
+    en = SimEngine(JTOP, URGENT_PA)
+    ej = SimEngine(JTOP, URGENT_PA, backend="jax")
+    for rng in ("independent", "shared"):
+        spec = QuerySpec(origins=(0, 17), n_trials=16, rng=rng)
+        rj, rn = ej.run(spec, pol), en.run(spec, pol)
+        _assert_metrics_equal(rj.metrics, rn.metrics, f"{name} {rng}")
+        np.testing.assert_array_equal(rj.values, rn.values)
+        np.testing.assert_array_equal(rj.indices, rn.indices)
+    assert len(rows) == 4 and max(rows) > sim_jax.URGENT_CHUNK, rows
+
+
 def test_jax_backend_pallas_kernel_path():
     """use_pallas=True routes every pairwise merge through the Pallas
     bitonic kernel (interpret mode off-TPU) — same bits as the default

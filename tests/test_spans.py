@@ -12,6 +12,8 @@ xplane:
     power-of-two bucket, ``d2h_bytes`` the bytes of the sweep outputs
     copied back (worked out here from the tree's shape), ``traced`` is
     0 on a warm engine, ``built`` is 0 on a warm plan;
+  * warmed on queries that send no urgent lists, a dispatch whose
+    queries do fetches them without compiling;
   * each result's ``extras["dispatch"]`` names its dispatch span;
   * answers are bit-identical with the profiler on and off.
 """
@@ -27,6 +29,8 @@ from jax.profiler import ProfileData
 
 from repro.engine import (QueryServer, QuerySpec, ServerConfig, SimEngine,
                           get_policy)
+from repro.engine import sim_jax
+from repro.engine.sim_jax import URGENT_CHUNK
 from repro.p2psim import SimParams, barabasi_albert
 
 TOP = barabasi_albert(96, m=2, seed=3)
@@ -187,9 +191,12 @@ def test_stats_count_what_they_say(served):
             assert [s.stats["entries"] for s in _named(spans, name)] \
                 == [4, 2]
 
-    # copies back: every level's outputs of the padded group, so the
-    # bytes follow from the tree's shape (f64 times and scores, int32
-    # owners, bool liveness under churn, int64 Strategy-1 skip counts)
+    # copies back: the packed rows of the padded group and the origin's
+    # list, so the bytes follow from the tree's shape (f64 send and
+    # arrival times over the reached peers, the origin sending no list;
+    # bool liveness under churn; the origin's f64 scores and int32
+    # owners; int64 Strategy-1 skip counts), plus the gathered lists of
+    # accepted urgent children in whole chunks of URGENT_CHUNK rows
     churn = policy.lifetime_mean_s != float("inf")
     st1 = policy.algorithm == "fd" and policy.strategy != "basic"
     for stage, sweep in zip(sorted(stages, key=lambda s: s.start),
@@ -203,14 +210,20 @@ def test_stats_count_what_they_say(served):
     order = [(0, 4), (17, 1), (0, 1), (17, 1)]
     for cb, (origin, rows) in zip(copies, order):
         reach, levels = _reached(served["engine"], origin, policy)
+        urgent = 0
         if policy.algorithm == "cn":
             per_row = reach * 8
             transfers = levels
         else:
-            per_row = (reach * 8 + (reach - 1) * 8 + reach * k * (8 + 4)
-                       + (reach if churn else 0) + (8 if st1 else 0))
-            transfers = (4 + churn) * levels - 1 + st1
-        assert cb.stats["d2h_bytes"] == rows * per_row, (origin, rows)
+            per_row = (reach * 8 + (reach - 1) * 8 + (reach if churn else 0)
+                       + k * (8 + 4) + (8 if st1 else 0))
+            transfers = 4 + churn + st1
+            assert cb.stats["urgent_rows"] >= 0
+            chunks = -(-cb.stats["urgent_rows"] // URGENT_CHUNK)
+            urgent = chunks * URGENT_CHUNK * k * (8 + 4)
+            transfers += 2 * chunks
+        assert cb.stats["d2h_bytes"] == rows * per_row + urgent, \
+            (origin, rows)
         assert cb.stats["transfers"] == transfers
 
 
@@ -251,3 +264,54 @@ def test_numpy_backend_records_server_and_engine_spans(tmp_path):
     assert tuple(r.extras["dispatch"] for r in results) == DISPATCH_OF
     built = [s.stats["built"] for s in _named(spans, "fd.engine.statics")]
     assert built == [2, 0]          # the cold plan builds both origins
+
+
+# link latencies and wait budgets under which late children are common
+# and many of their urgent lists reach the origin in time
+URGENT_PA = SimParams(seed=11, latency_mean_s=0.2, latency_var=0.3 ** 2,
+                      t_qsnd_s=0.2, t_slsnd_s=0.3)
+QUIET_SEEDS = {0: 8, 17: 2}          # per origin: no urgent list accepted
+URGENT_SEEDS = ((0, 19), (0, 22), (17, 12), (17, 10))
+
+
+@pytest.mark.parametrize("name", ["fd-dynamic", "fd-dynamic-churn"])
+def test_warm_gather_serves_urgent_rows_without_compiling(name, tmp_path,
+                                                         monkeypatch):
+    """``QueryServer.warm`` on queries that send no urgent lists still
+    compiles the urgent-row gather, so a served dispatch whose queries
+    do fetch urgent rows traces nothing: every sweep span reads
+    ``traced`` 0 and no result reports ``jax_traces``."""
+    policy = POLICIES[name]
+    rows = []
+    accept = sim_jax._accept_urgent_origin
+
+    def spy(org_v, org_o, ue, cv, co, k):
+        rows.append(len(ue))
+        accept(org_v, org_o, ue, cv, co, k)
+    monkeypatch.setattr(sim_jax, "_accept_urgent_origin", spy)
+    engine = SimEngine(TOP, URGENT_PA, backend="jax")
+    warm = QueryServer(engine)
+    for o, seed in QUIET_SEEDS.items():
+        warm.warm(QuerySpec(origins=(o,), seeds=[[seed]]), policy,
+                  batch_sizes=BUCKETS)
+    assert rows and not any(rows), rows
+    rows.clear()
+    server = QueryServer(engine, ServerConfig(max_batch=4,
+                                              batch_window_s=0.05))
+    handles = [server.submit(QuerySpec(origins=(o,), seeds=[[seed]]),
+                             policy) for o, seed in URGENT_SEEDS]
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        server.start()
+        results = [h.result(timeout=300) for h in handles]
+        server.stop()
+    spans = _read_spans(str(tmp_path))
+    sweeps = _named(spans, "fd.engine.sweep")
+    copies = _named(spans, "fd.engine.copy_back")
+    assert len(sweeps) == len(copies) == 2          # one dispatch, 2 origins
+    assert all(s.stats["traced"] == 0 for s in sweeps)
+    assert all(r.extras.get("jax_traces", 0) == 0 for r in results)
+    assert all(r.compile_s == 0 for r in results)
+    assert sum(rows) > 0
+    assert sum(c.stats["urgent_rows"] for c in copies) == sum(rows)

@@ -79,6 +79,16 @@ def test_keep_n_and_async(tmp_path):
     assert steps == [4, 5]
 
 
+def test_keep_n_blocking(tmp_path):
+    """A finished write is not counted among the older checkpoints."""
+    mgr = CheckpointManager(str(tmp_path), save_every=1, keep=2,
+                            blocking=True)
+    for s in range(1, 6):
+        mgr.maybe_save(s, _tree())
+    assert sorted(os.listdir(tmp_path)) == ["step_00000004",
+                                            "step_00000005"]
+
+
 def test_restore_latest_resume(tmp_path):
     mgr = CheckpointManager(str(tmp_path), save_every=1, blocking=True)
     t = _tree()
