@@ -42,8 +42,9 @@ def main() -> int:
         run = ses.window(ses.traffic, seed, args.seconds, False)
         sampled = check.sample(run.answered, run.batches,
                                int(run.traffic["check_sample"]), seed)
-        verdict = check.compare(run.requests, sampled, ses.ov.neighbors,
-                                run.config, run.config["check"])
+        verdict = check.compare(run.requests, sampled, ses.ov,
+                                run.config, run.config["check"],
+                                ses.reference)
         print(json.dumps({"seed": seed, "precision": args.precision,
                           "correct": verdict["ok"],
                           "sampled": verdict["sampled"],

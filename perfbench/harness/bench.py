@@ -4,7 +4,16 @@ Everything that belongs to one configuration, one traffic mix or one
 metric sits in a file of its own under ``perfbench/``:
 
 * ``configs/<config>.json``: a deployment (the ``file`` of its
-  ``configs`` entry);
+  ``configs`` entry).  Beside its sizes it names two files by key:
+  ``overlay.family`` its overlay generator and ``reference`` its plain
+  reference;
+* ``overlays/<family>.py``: a generator with ``build(peers,
+  topology_seed, **family_args)`` that returns a
+  ``harness.overlay.Overlay``;
+* ``references/<reference>.py``: the plain reference of the answers,
+  with ``params(config)``, which refuses a configuration key it does
+  not know, and ``query(ov, origin, seed, config)``, which returns a
+  ``harness.reference.Answer`` (see ``harness.check``);
 * ``traffic/<traffic>.json``: a traffic mix (see ``harness.traffic``);
 * ``metrics/<metric>.py``: a reader with ``read(run)`` that returns the
   metric's value from a finished run (``harness.cell.Run``), or None
@@ -12,14 +21,16 @@ metric sits in a file of its own under ``perfbench/``:
   reports in (``<metric>.<part>``, as ``engine_s_per_query.closed``)
   is read by the reader of ``<metric>``.
 
-A new cell or metric is new files plus entries in ``BENCHMARK.json``;
-nothing here changes.
+A new deployment, cell or metric is new files plus entries in
+``BENCHMARK.json``; nothing here changes.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 import os
+import re
+import sys
 from typing import Callable, Dict, List
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -54,16 +65,29 @@ def cell(bench: dict, workload: str, root: str = CHECKOUT) -> dict:
             "per_layer": mine(bench["per_layer"])}
 
 
+def module(kind: str, name: str, root: str = CHECKOUT):
+    """The module ``perfbench/<kind>/<name>.py`` under ``root``; an
+    unknown ``name`` is an error that lists the names there are."""
+    folder = os.path.join(root, os.path.basename(HERE), kind)
+    path = os.path.join(folder, name + ".py")
+    if not os.path.isfile(path):
+        known = sorted(f[:-3] for f in os.listdir(folder)
+                       if f.endswith(".py")) if os.path.isdir(folder) else []
+        raise ValueError(f"no {kind} file {name!r} under {folder}; "
+                         f"known: {known}")
+    key = re.sub(r"\W", "_", f"perfbench_{kind}_{name}")
+    spec = importlib.util.spec_from_file_location(key, path)
+    mod = importlib.util.module_from_spec(spec)
+    # a dataclass under postponed annotations looks its module up here
+    sys.modules[key] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(name: str) -> Callable:
     """``read`` of ``metrics/<base>.py``, ``base`` being ``name`` up to
     its first dot."""
-    base = name.split(".", 1)[0]
-    path = os.path.join(HERE, "metrics", base + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "perfbench_metric_" + base.replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return module("metrics", name.split(".", 1)[0]).read
 
 
 def read_metrics(entries: List[dict], run) -> Dict[str, dict]:

@@ -169,8 +169,10 @@ class Session:
                     "count": len(d)}
         self.info = info
         self.counter = CompileCounter()
+        self.reference = check.load_reference(cfg, root)
         self.ov = ov = overlay.build(cfg["overlay"],
-                                     check=config_override is None)
+                                     check=config_override is None,
+                                     root=root)
         top = Topology(ov.n, ov.neighbors, ov.kind, coords=ov.coords)
         prec = precision or cfg["precision"]
         self.engine = SimEngine(top, SimParams(**cfg["params"]),
@@ -310,8 +312,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     t0 = time.perf_counter()
     sampled = check.sample(run.answered, run.batches,
                            int(run.traffic["check_sample"]), seed)
-    verdict = check.compare(run.requests, sampled, ses.ov.neighbors,
-                            run.config, run.config["check"])
+    verdict = check.compare(run.requests, sampled, ses.ov, run.config,
+                            run.config["check"], ses.reference)
     log(f"reference: {len(sampled)} answers recomputed in "
         f"{time.perf_counter() - t0:.3f} s")
 

@@ -2,9 +2,12 @@
 
 Every answer the window produced is one query's top-k and its traffic
 figures.  After the window a sample of the answered requests, drawn
-from ``--seed``, is recomputed by the plain reference on the same
-overlay, origin, seed and parameters, and each compared number is held
-to its limit from the configuration's ``check`` block:
+from ``--seed``, is recomputed on the same overlay, origin, seed and
+parameters by the plain reference the configuration names
+(``references/<reference>.py``: ``query`` returns a
+``harness.reference.Answer``, whose fields are the contract), and each
+compared number is held to its limit from the configuration's
+``check`` block:
 
 ``unanswered``
     Requests due in the window that were shed, failed, or not answered
@@ -25,11 +28,11 @@ from __future__ import annotations
 
 import math
 import sys
-from typing import List
+from typing import Callable, List
 
 import numpy as np
 
-from harness import reference
+from harness import bench
 
 COUNTS = ("n_reached", "m_fw", "b_fw", "m_bw", "b_bw", "m_rt", "b_rt")
 ORDER = ("unanswered", "owner_mismatch", "value_rel_gap",
@@ -61,6 +64,15 @@ def sample(answered: list, batches: List[List[int]], count: int,
     return [answered[i] for i in sorted(keep)]
 
 
+def load_reference(config: dict, root: str = bench.CHECKOUT) -> Callable:
+    """``query`` of the reference ``config`` names, once its ``params``
+    has accepted the configuration: a key it does not know fails here,
+    at set-up, not after the window."""
+    mod = bench.module("references", config["reference"], root)
+    mod.params(config)
+    return mod.query
+
+
 def _worst(a: float, b: float) -> float:
     """The larger of two gaps; NaN, which no limit holds, wins."""
     return b if (math.isnan(b) or b > a) else a
@@ -70,19 +82,16 @@ def _field(result, name: str):
     return getattr(result.metrics, name).reshape(-1)[0]
 
 
-def compare(requests: List, sampled: List, neighbors, config: dict,
-            limits: dict) -> dict:
-    """Each compared number beside its limit; ``ok`` when all hold."""
-    pol = config["policy"]
-    params = reference.Params(**config["params"])
+def compare(requests: List, sampled: List, ov, config: dict,
+            limits: dict, query: Callable) -> dict:
+    """Each compared number beside its limit; ``ok`` when all hold.
+    ``query`` is the configuration's reference (``load_reference``), asked
+    for each sampled request on the overlay ``ov``."""
     nums = dict.fromkeys(ORDER, 0)
     nums["unanswered"] = sum(1 for r in requests
                              if math.isinf(r.latency_s))
     for r in sampled:
-        ref = reference.fd_query(
-            neighbors, r.origin, r.seed, params,
-            strategy=pol["strategy"], dynamic=pol["dynamic"],
-            lifetime_mean_s=lifetime(pol))
+        ref = query(ov, r.origin, r.seed, config)
         got = r.result
         vals = np.asarray(got.values, np.float64).reshape(-1)
         owns = np.asarray(got.indices).reshape(-1)

@@ -1,13 +1,16 @@
 """The overlay a configuration names, built from its topology seed.
 
-The benchmark makes its own data: these generators are copies of the
-program's BRITE-style BA and Gnutella-like generators (the same
-construction and random streams; ``gnutella`` differs in how it
-reconnects fragments), so the overlay handed to the system under test
-and the one the reference floods are the benchmark's, not the
-program's.  A configuration pins the result by its edge count and the
-degree of each fixed origin, and ``build`` refuses an overlay that does
-not match.
+The benchmark makes its own data.  A configuration's ``overlay`` block
+names its generator by ``family``: ``perfbench/overlays/<family>.py``,
+whose ``build(peers, topology_seed, **family_args)`` returns an
+``Overlay``; a new deployment brings its generator as a new file.  The
+generators there are copies of the program's (the same construction
+and random streams; ``gnutella`` differs in how it reconnects
+fragments), so the overlay handed to the system under test and the one
+the reference floods are the benchmark's, not the program's.  This
+module holds what they share.  A configuration pins the result by its
+edge count and the degree of each fixed origin, and ``build`` refuses
+an overlay that does not match.
 """
 from __future__ import annotations
 
@@ -15,6 +18,8 @@ import dataclasses
 from typing import List, Optional
 
 import numpy as np
+
+from harness import bench
 
 
 @dataclasses.dataclass
@@ -96,59 +101,17 @@ def _rejoin(adj: List[set], rng: np.random.Generator) -> None:
         adj[b].add(a)
 
 
-def ba(n: int, seed: int, m: int = 2) -> Overlay:
-    """BRITE's flat BA model: m edges per joining peer (d(G) about 2m)."""
-    adj = _ba_adj(n, m, np.random.default_rng(seed))
-    return Overlay("ba", [np.array(sorted(a), np.int32) for a in adj])
-
-
-def gnutella(n: int, seed: int, m: int = 2,
-             rewire_p: float = 0.10) -> Overlay:
-    """A BA core whose edges are each re-pointed, with ``rewire_p``,
-    from the higher endpoint to a uniform peer (host-cache shortcuts);
-    a rewire that would make a self-loop or a duplicate keeps the edge.
-    Coordinates are uniform in the unit square.
-
-    Rewiring cuts off some hundreds of low-degree peers.  The program's
-    own ``gnutella`` generator chains those fragments into one path
-    (``_bridge_chain``), which gives a 400-hop flood tree at 100k peers;
-    here each fragment rejoins the largest component instead
-    (``_rejoin``), as a real servent would."""
-    rng = np.random.default_rng(seed)
-    adj = _ba_adj(n, m, rng)
-    coords = rng.random((n, 2))
-    edges = [(u, int(v)) for u in range(n) for v in adj[u] if u < v]
-    flips = rng.random(len(edges)) < rewire_p
-    targets = rng.integers(0, n, len(edges))
-    for (u, v), flip, w in zip(edges, flips, targets):
-        w = int(w)
-        if not flip or w == u or w in adj[u] or v not in adj[u]:
-            continue
-        adj[u].discard(v)
-        adj[v].discard(u)
-        adj[u].add(w)
-        adj[w].add(u)
-    _rejoin(adj, rng)
-    return Overlay("gnutella", [np.array(sorted(a), np.int32) for a in adj],
-                   coords)
-
-
-FAMILIES = {"ba": ba, "gnutella": gnutella}
-
-
-def build(overlay_cfg: dict, check: bool = True) -> Overlay:
-    """The overlay of a configuration's ``overlay`` block.
+def build(overlay_cfg: dict, check: bool = True,
+          root: str = bench.CHECKOUT) -> Overlay:
+    """The overlay of a configuration's ``overlay`` block, made by the
+    generator its ``family`` names under ``root``.
 
     ``check`` holds it to the block's ``edges`` and to the median degree
     at every fixed origin, so a generator that drifted cannot go unseen.
     """
-    fam = overlay_cfg["family"]
-    if fam not in FAMILIES:
-        raise ValueError(f"unknown overlay family {fam!r}; known: "
-                         f"{sorted(FAMILIES)}")
-    ov = FAMILIES[fam](int(overlay_cfg["peers"]),
-                       int(overlay_cfg["topology_seed"]),
-                       **overlay_cfg.get("family_args", {}))
+    gen = bench.module("overlays", overlay_cfg["family"], root).build
+    ov = gen(int(overlay_cfg["peers"]), int(overlay_cfg["topology_seed"]),
+             **overlay_cfg.get("family_args", {}))
     if check:
         if ov.n_edges != overlay_cfg["edges"]:
             raise ValueError(f"overlay has {ov.n_edges} edges, the "
